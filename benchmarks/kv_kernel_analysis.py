@@ -17,7 +17,9 @@ import json
 import os
 
 from repro.configs.base import ARCH_IDS, SHAPES, get_config
-from repro.roofline.analysis import HBM_BW, hbm_bandwidth_row
+from repro.roofline.analysis import TARGET_KIND, chip_peaks, hbm_bandwidth_row
+
+HBM_BW = chip_peaks(TARGET_KIND).hbm_bw
 
 BLOCK = 8
 VMEM_BUDGET = 16 * 2**20
@@ -144,7 +146,7 @@ def attend_paged_cell(cfg, shape_name: str, keep: int = 4,
         flops = 4.0 * L * b_loc * nq_loc * hd * (tiles * g + 1) * BLOCK
         row = hbm_bandwidth_row(
             bytes_step, flops, grid_steps=grid_steps,
-            mxu_efficiency=min(1.0, g * BLOCK / 128))
+            mxu_efficiency=min(1.0, g * BLOCK / 128), device_kind=TARGET_KIND)
         row["grid_blocks"] = grid_blocks
         row["g"] = g
         return row

@@ -35,6 +35,7 @@ from repro.core import kv_cache as kvc
 from repro.data.synthetic import TokenStream
 from repro.models import api as model_api
 from repro.optim.adamw import AdamWConfig
+from repro.parallel import mesh as mesh_lib
 from repro.serve import engine as E
 from repro.train import step as train_step
 
@@ -45,7 +46,7 @@ def train_params(api, ts, steps: int):
         optimizer=AdamWConfig(lr=3e-3, warmup_steps=3, total_steps=steps + 20))
     state = train_step.init_train_state(api, tc)
     step = jax.jit(train_step.make_train_step(
-        api, jax.make_mesh((1,), ("data",)), tc), donate_argnums=(0,))
+        api, mesh_lib.make_mesh((1,), ("data",)), tc), donate_argnums=(0,))
     m = {"loss": jnp.nan}  # steps=0 benchmarks the untrained model
     for i in range(steps):
         b = {k: jnp.asarray(v) for k, v in ts.batch(i).items()}

@@ -56,6 +56,7 @@ import numpy as np
 from repro.configs.base import get_config
 from repro.models import api as model_api
 from repro.parallel import mesh as mesh_lib
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import engine as E
 
 ART = pathlib.Path(__file__).parent / "artifacts"
@@ -144,6 +145,7 @@ def main(argv=None):
     ap.add_argument("--mesh", default=None,
                     help="DATAxMODEL serve mesh, e.g. 4x1 (default: none)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config("qwen2_0_5b").reduced()
     api = model_api.build("qwen2_0_5b", cfg)

@@ -1,11 +1,12 @@
 """Fused Pallas `pallas` codec backend.
 
-Wraps the raw kernels in `repro.kernels.*` with the one policy decision they
-need — compile vs interpret — taken from `repro.codec.dispatch` instead of
-being copy-pasted at every call site.  Layout conversion between the
-kernels' plane-packed int8 output ``(R*k/8, C*k/8)`` and the repo-canonical
-blocks layout ``(R/8, C/8, k, k)`` happens here, so consumers only ever see
-one compressed representation regardless of backend.
+Wraps the raw kernels in `repro.kernels.*` with the policy decisions they
+need: compile vs interpret, taken from `repro.codec.dispatch` instead of
+being copy-pasted at every call site, and, under a mesh, a per-device
+`shard_map` over the plane's block rows (`sharding.kernel_map`: GSPMD
+cannot partition a Mosaic kernel).  The fused codec kernels take and
+return the repo-canonical blocks layout ``(R/8, C/8, k, k)``, so consumers
+only ever see one compressed representation regardless of backend.
 
 This backend is the default on TPU (see dispatch.resolve_backend_name); on
 CPU it runs the kernels in interpret mode, which the parity tests in
@@ -13,13 +14,17 @@ tests/test_codec_backends.py use to pin it against `reference`.
 """
 from __future__ import annotations
 
+import functools
+
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.codec import dispatch
 from repro.core import quantize as quant_lib
 from repro.kernels.dct8x8 import kernel as dct_kernel
 from repro.kernels.fused_compress import kernel as fc_kernel
 from repro.kernels.quant_pack import kernel as qp_kernel
+from repro.parallel import sharding as sh
 
 BLOCK = 8
 
@@ -40,21 +45,21 @@ class PallasBackend:
         )
 
     def compress_plane(self, x: jnp.ndarray, keep: int):
-        packed, scale = fc_kernel.compress_plane_pallas(
-            x, keep, interpret=self.interpret
-        )
-        nh, nw = scale.shape
-        q = packed.reshape(nh, keep, nw, keep)
-        return jnp.swapaxes(q, 1, 2), scale
+        rows = sh.kernel_rows_axes(x.shape[0] // BLOCK)
+        return sh.kernel_map(
+            functools.partial(fc_kernel.compress_plane_pallas, keep=keep,
+                              interpret=self.interpret),
+            in_specs=P(rows, None),
+            out_specs=(P(rows, None, None, None), P(rows, None)))(x)
 
     def decompress_plane(self, q: jnp.ndarray, scale: jnp.ndarray,
                          out_dtype=jnp.float32) -> jnp.ndarray:
-        keep = q.shape[-1]
-        nh, nw = scale.shape
-        packed = jnp.swapaxes(q, 1, 2).reshape(nh * keep, nw * keep)
-        return fc_kernel.decompress_plane_pallas(
-            packed, scale, keep, out_dtype=out_dtype, interpret=self.interpret
-        )
+        rows = sh.kernel_rows_axes(q.shape[0])
+        return sh.kernel_map(
+            functools.partial(fc_kernel.decompress_plane_pallas,
+                              out_dtype=out_dtype, interpret=self.interpret),
+            in_specs=(P(rows, None, None, None), P(rows, None)),
+            out_specs=P(rows, None))(q, scale)
 
     def quant_pack_plane(self, x: jnp.ndarray, fmin, fmax, level: int,
                          bits: int = 8):

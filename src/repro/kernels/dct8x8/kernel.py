@@ -26,6 +26,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.dct import _dct_matrix_np
+from repro.kernels import resolve_interpret
 
 BLOCK = 8
 
@@ -59,13 +60,15 @@ def dct2_plane_pallas(
     inverse: bool = False,
     tile_r: int = 128,
     tile_c: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Blocked 2-D DCT of a (R, C) plane; R, C multiples of 8.
 
     Pads to tile multiples (zero padding only ever adds whole 8x8 blocks whose
     coefficients are sliced off again), runs the tiled Pallas kernel.
+    interpret=None: compiled on TPU, interpreted elsewhere.
     """
+    interpret = resolve_interpret(interpret)
     r, c = x.shape
     assert r % BLOCK == 0 and c % BLOCK == 0, (r, c)
     tr = min(tile_r, r)

@@ -51,22 +51,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.dct import _dct_matrix_np
+from repro.kernels import resolve_interpret
 
 BLOCK = 8
 
 
 def _dct_k_np(keep: int) -> np.ndarray:
     return _dct_matrix_np(BLOCK)[:keep].astype(np.float32)
-
-
-def _resolve_interpret(interpret: bool | None) -> bool:
-    """Platform auto-selection via the codec dispatch rules — compiled on
-    TPU, interpret elsewhere (CPU CI), REPRO_CODEC_INTERPRET override. The
-    same resolution ops.py applies, so direct kernel callers never silently
-    run interpreted on TPU."""
-    from repro.codec import dispatch as codec_dispatch  # lazy: no cycle
-
-    return codec_dispatch.resolve_interpret(interpret)
 
 
 def fit_tile(requested: int, total: int, unit: int = BLOCK) -> int:
@@ -155,7 +146,7 @@ def attend_compressed_plane(
     tail merging. out = acc / l after merging. interpret=None auto-selects
     via the codec dispatch rules (compiled on TPU, interpret elsewhere).
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     ns, nh, k, _ = packed_k.shape
     s_total = ns * BLOCK
     hd = nh * BLOCK
@@ -197,19 +188,50 @@ def attend_compressed_plane(
 # Paged pool: gather history through the block table (scalar prefetch)
 # ---------------------------------------------------------------------------
 
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _dot(a, b):
+    return jax.lax.dot(a, b, precision=_HI, preferred_element_type=jnp.float32)
+
+
+def _dot_nt(a, b):
+    """a @ b.T without materializing the transpose."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _paged_consts_np(keep: int, nh: int, g_pages: int):
+    """Constant IDCT placements for the paged kernel.
+
+    ckg (G, k, G*8): ckg[g, u, g*8 + a] = C8[u, a] — row-side IDCT of page g
+        landing at its own 8 sequence columns of the score tile.
+    bde (nh, k, hd): bde[j, v, j*8 + b] = C8[v, b] — column-side IDCT of
+        head-dim block j landing at its own 8 lanes of the output.
+    """
+    ck = _dct_k_np(keep)
+    ckg = np.zeros((g_pages, keep, g_pages * BLOCK), np.float32)
+    for g in range(g_pages):
+        ckg[g, :, g * BLOCK:(g + 1) * BLOCK] = ck
+    bde = np.zeros((nh, keep, nh * BLOCK), np.float32)
+    for j in range(nh):
+        bde[j, :, j * BLOCK:(j + 1) * BLOCK] = ck
+    return ckg, bde
+
+
 def _attend_paged_kernel(
     pos_ref,                    # scalar prefetch: (B,) int32
     bt_ref,                     # scalar prefetch: (B, nblocks) int32 page ids
-    *refs,                      # 4*G page refs, q, ck, tails, out, scratch
-    g_pages: int, scale: float,
+    *refs,                      # 4*G page refs, qh, q, ckg, bde, tails, out, scratch
+    g_pages: int, nh: int, scale: float,
 ):
     page_refs = refs[:4 * g_pages]      # (pk, sk, pv, sv) per gathered page
-    (q_ref, ck_ref, tk_ref, tv_ref, o_ref,
+    (qh_ref, q_ref, ckg_ref, bde_ref, tk_ref, tv_ref, o_ref,
      m_ref, l_ref, acc_ref) = refs[4 * g_pages:]
     b = pl.program_id(0)
+    h = pl.program_id(1)
     step = pl.program_id(2)             # one G-page tile per grid step
     tile_s = g_pages * BLOCK
-    ck = ck_ref[...]                    # (k, 8) DCT constant (VMEM)
 
     @pl.when(step == 0)
     def _init():
@@ -222,21 +244,19 @@ def _attend_paged_kernel(
 
     @pl.when(tile0 < flushed)           # skip tiles wholly past the watermark
     def _tile():
-        def dec(p_ref, s_ref):
-            """One int8 page -> f32 (8, hd): per-8x8-block z -> Ck^T z Ck."""
-            z = p_ref[0, 0].astype(jnp.float32) * s_ref[0, 0][..., None, None]
-            t = jnp.einsum("ua,juv,vb->ajb", ck, z, ck)     # (8, nh, 8)
-            return t.reshape(BLOCK, -1)
-
-        kt = jnp.concatenate(
-            [dec(page_refs[4 * g], page_refs[4 * g + 1])
-             for g in range(g_pages)], axis=0)              # (G*8, hd)
-        vt = jnp.concatenate(
-            [dec(page_refs[4 * g + 2], page_refs[4 * g + 3])
-             for g in range(g_pages)], axis=0)
-
-        q = q_ref[0, 0].astype(jnp.float32) * scale         # (n_rep, hd)
-        s = jax.lax.dot(q, kt.T, preferred_element_type=jnp.float32)
+        # Scores in the DCT domain: the query arrives pre-transformed per
+        # head-dim block (qh[j] = q_j C8[:k]^T), so a page's K rows are
+        # never rebuilt — per block j one (n_rep, k) x (k, k)^T product
+        # scaled by the block's f32 scale (SMEM), then the row-side IDCT
+        # places the page's 8 positions in the (n_rep, G*8) score tile.
+        s = jnp.zeros((qh_ref.shape[3], tile_s), jnp.float32)
+        for g in range(g_pages):
+            pk = page_refs[4 * g][0, 0].astype(jnp.float32)    # (nh, k, k)
+            sk = page_refs[4 * g + 1]
+            w = sk[0, h, 0] * _dot_nt(qh_ref[0, 0, 0], pk[0])
+            for j in range(1, nh):
+                w = w + sk[0, h, j] * _dot_nt(qh_ref[0, 0, j], pk[j])
+            s = s + _dot(w, ckg_ref[g])                        # (n_rep, G*8)
         kv_pos = tile0 + jax.lax.broadcasted_iota(jnp.int32, (1, tile_s), 1)
         valid = kv_pos < flushed        # flushed blocks only
         s = jnp.where(valid, s, -jnp.inf)
@@ -247,8 +267,22 @@ def _attend_paged_kernel(
         p = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
         alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p, vt, preferred_element_type=jnp.float32)
+
+        # P @ V the same way round: the row-side IDCT folds into P
+        # (ph = P C8-placed^T), per block j the scaled (k, k) page block,
+        # then the column-side IDCT places block j at its 8 output lanes.
+        ys = [None] * nh
+        for g in range(g_pages):
+            ph = _dot_nt(p, ckg_ref[g])                         # (n_rep, k)
+            pv = page_refs[4 * g + 2][0, 0].astype(jnp.float32)
+            sv = page_refs[4 * g + 3]
+            for j in range(nh):
+                y = sv[0, h, j] * _dot(ph, pv[j])
+                ys[j] = y if ys[j] is None else ys[j] + y
+        pv_out = _dot(ys[0], bde_ref[0])
+        for j in range(1, nh):
+            pv_out = pv_out + _dot(ys[j], bde_ref[j])           # (n_rep, hd)
+        acc_ref[...] = acc_ref[...] * alpha + pv_out
         m_ref[...] = m_new
 
     @pl.when(step == pl.num_programs(2) - 1)
@@ -257,9 +291,9 @@ def _attend_paged_kernel(
         # tail ring; same online-softmax algebra, then normalize — the
         # kernel output is the finished attention, no XLA pass after it.
         q = q_ref[0, 0].astype(jnp.float32) * scale
-        tk = tk_ref[0, :, 0].astype(jnp.float32)            # (8, hd)
-        tv = tv_ref[0, :, 0].astype(jnp.float32)
-        st = jax.lax.dot(q, tk.T, preferred_element_type=jnp.float32)
+        tk = tk_ref[0, 0].astype(jnp.float32)               # (8, hd)
+        tv = tv_ref[0, 0].astype(jnp.float32)
+        st = _dot_nt(q, tk)
         tail_pos = flushed + jax.lax.broadcasted_iota(jnp.int32, (1, BLOCK), 1)
         tvalid = tail_pos <= pos_ref[b]
         st = jnp.where(tvalid, st, -jnp.inf)
@@ -269,8 +303,7 @@ def _attend_paged_kernel(
         pt = jnp.where(tvalid, jnp.exp(st - m_safe), 0.0)
         alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
         l2 = l_ref[...] * alpha + jnp.sum(pt, axis=-1, keepdims=True)
-        acc2 = acc_ref[...] * alpha + jax.lax.dot(
-            pt, tv, preferred_element_type=jnp.float32)
+        acc2 = acc_ref[...] * alpha + _dot(pt, tv)
         o_ref[0, 0] = acc2 / jnp.maximum(l2, 1e-30)
 
 
@@ -296,46 +329,69 @@ def attend_paged(
     G pages, so the kernel DMAs exactly the pages the table names — HBM
     traffic is the compressed pages, never the dense (B, S/8, ...) layout.
     Unmapped table entries are 0 (a valid page, masked by the flushed
-    watermark); tiles wholly past the watermark skip compute via pl.when.
+    watermark) and ids outside the pool clamp to its ends, as in interpret
+    mode; tiles wholly past the watermark skip compute via pl.when.
     `block_table` may be a decode-ladder bucket slice of the full table —
     the grid covers only the slice. `pages_per_tile` shrinks to the largest
     divisor of the table width (G=1 reproduces single-page stepping).
 
+    Every block the kernel reads satisfies the TPU tiling rule (last two
+    block dims equal to the array's): a page's int8 corners come in as
+    (hd/8, k, k), its scales as the page's whole (Hkv, hd/8) row in SMEM,
+    and the tail ring is handed over head-major (B, Hkv, 8, hd).  The
+    decompress never reshapes across tiled dims: scores and P @ V run in
+    the DCT domain with 2-D products only (see `_attend_paged_kernel`).
+
     Returns the NORMALIZED attention output (B, Hkv, n_rep, hd) f32 — the
     raw-tail merge runs in the kernel's finalize step.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     n_pages, hkv, nh, k, _ = packed_k.shape
     hd = nh * BLOCK
     b, _, n_rep, _ = q.shape
     nblocks = block_table.shape[1]
     g_pages = fit_tile(pages_per_tile, nblocks, unit=1)
+    scale = 1.0 / float(np.sqrt(hd))
+    ckg, bde = _paged_consts_np(k, nh, g_pages)
+    # query per head-dim block, forward-DCT'd along the block and truncated
+    # to the kept k frequencies: qh[b, h, j, r, v] = sum_c q[.., j*8+c] C8[v, c]
+    qh = jnp.einsum("bhrjc,vc->bhjrv",
+                    q.astype(jnp.float32).reshape(b, hkv, n_rep, nh, BLOCK),
+                    jnp.asarray(_dct_k_np(k)), precision=_HI) * scale
+    tk = jnp.swapaxes(tail_k, 1, 2)     # (B, Hkv, 8, hd)
+    tv = jnp.swapaxes(tail_v, 1, 2)
+
+    def page(bt, bi, i, g):
+        # clamp like the interpreter's dynamic slice does: a compiled DMA
+        # past the pool halts the chip instead
+        return jnp.clip(bt[bi, i * g_pages + g], 0, n_pages - 1)
 
     page_specs = []
     for g in range(g_pages):
-        idx5 = lambda bi, h, i, pos, bt, g=g: \
-            (bt[bi, i * g_pages + g], h, 0, 0, 0)
-        idx3 = lambda bi, h, i, pos, bt, g=g: (bt[bi, i * g_pages + g], h, 0)
+        idx5 = lambda bi, h, i, pos, bt, g=g: (page(bt, bi, i, g), h, 0, 0, 0)
+        idx3 = lambda bi, h, i, pos, bt, g=g: (page(bt, bi, i, g), 0, 0)
         page_specs += [
             pl.BlockSpec((1, 1, nh, k, k), idx5),
-            pl.BlockSpec((1, 1, nh), idx3),
+            pl.BlockSpec((1, hkv, nh), idx3, memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, nh, k, k), idx5),
-            pl.BlockSpec((1, 1, nh), idx3),
+            pl.BlockSpec((1, hkv, nh), idx3, memory_space=pltpu.SMEM),
         ]
+    const = lambda *shape: pl.BlockSpec(
+        shape, lambda bi, h, i, pos, bt: (0,) * len(shape))
+    per_head = lambda *shape: pl.BlockSpec(
+        (1, 1) + shape, lambda bi, h, i, pos, bt: (bi, h) + (0,) * len(shape))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hkv, nblocks // g_pages),
         in_specs=page_specs + [
-            pl.BlockSpec((1, 1, n_rep, hd),
-                         lambda bi, h, i, pos, bt: (bi, h, 0, 0)),
-            pl.BlockSpec((k, BLOCK), lambda bi, h, i, pos, bt: (0, 0)),
-            pl.BlockSpec((1, BLOCK, 1, hd),
-                         lambda bi, h, i, pos, bt: (bi, 0, h, 0)),
-            pl.BlockSpec((1, BLOCK, 1, hd),
-                         lambda bi, h, i, pos, bt: (bi, 0, h, 0)),
+            per_head(nh, n_rep, k),          # qh
+            per_head(n_rep, hd),             # q (tail merge)
+            const(g_pages, k, g_pages * BLOCK),
+            const(nh, k, hd),
+            per_head(BLOCK, hd),             # tail_k, head-major
+            per_head(BLOCK, hd),             # tail_v
         ],
-        out_specs=pl.BlockSpec((1, 1, n_rep, hd),
-                               lambda bi, h, i, pos, bt: (bi, h, 0, 0)),
+        out_specs=per_head(n_rep, hd),
         scratch_shapes=[
             pltpu.VMEM((n_rep, 1), jnp.float32),   # m
             pltpu.VMEM((n_rep, 1), jnp.float32),   # l
@@ -346,10 +402,10 @@ def attend_paged(
     # BlockSpec walks its own table stride, XLA aliases the operands
     pages = (packed_k, scale_k, packed_v, scale_v) * g_pages
     return pl.pallas_call(
-        functools.partial(_attend_paged_kernel, g_pages=g_pages,
-                          scale=1.0 / float(np.sqrt(hd))),
+        functools.partial(_attend_paged_kernel, g_pages=g_pages, nh=nh,
+                          scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, n_rep, hd), jnp.float32),
         interpret=interpret,
     )(pos.astype(jnp.int32), block_table.astype(jnp.int32),
-      *pages, q, jnp.asarray(_dct_k_np(k)), tail_k, tail_v)
+      *pages, qh, q, jnp.asarray(ckg), jnp.asarray(bde), tk, tv)

@@ -9,6 +9,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.codec import dispatch as codec_dispatch
 from repro.core.kv_cache import as_pos_vec
@@ -16,6 +17,7 @@ from repro.kernels.fused_attend.kernel import (
     attend_compressed_plane,
     attend_paged,
 )
+from repro.parallel import sharding as sh
 from repro.parallel.sharding import attn_hint
 
 BLOCK = 8
@@ -54,13 +56,21 @@ def attend_with_tail(
     qg = q[:, 0].reshape(b, hkv, n_rep, hd)
 
     if block_table is not None:
-        out = attend_paged(
-            layer_cache["packed_k"], layer_cache["scale_k"],
-            layer_cache["packed_v"], layer_cache["scale_v"],
-            qg, pos, block_table,
-            layer_cache["tail_k"], layer_cache["tail_v"],
-            pages_per_tile=pages_per_tile, interpret=interpret,
-        )  # (B, Hkv, n_rep, hd) normalized — tail merged in-kernel
+        # under a mesh each device attends its own slots against the whole
+        # pool: a slot's pages can sit on any data shard (the allocator is
+        # device-agnostic), so the pool planes enter the kernel replicated
+        rows = sh.kernel_rows_axes(b)
+        out = sh.kernel_map(
+            functools.partial(attend_paged, pages_per_tile=pages_per_tile,
+                              interpret=interpret),
+            in_specs=(P(), P(), P(), P(), P(rows), P(rows), P(rows),
+                      P(rows), P(rows)),
+            out_specs=P(rows),
+        )(layer_cache["packed_k"], layer_cache["scale_k"],
+          layer_cache["packed_v"], layer_cache["scale_v"],
+          qg, pos, block_table,
+          layer_cache["tail_k"], layer_cache["tail_v"],
+          )  # (B, Hkv, n_rep, hd) normalized — tail merged in-kernel
         return attn_hint(out.reshape(b, 1, h, hd).astype(q.dtype))
 
     # (B, S/8, Hkv, hd/8, k, k) -> planes (B, Hkv, S/8, hd/8, k, k)

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 BLOCK = 8
 
 
@@ -43,8 +45,10 @@ def quant_pack_plane_pallas(
     bits: int = 8,
     tile_r: int = 128,
     tile_c: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
+    """interpret=None: compiled on TPU, interpreted elsewhere."""
+    interpret = resolve_interpret(interpret)
     r, c = x.shape
     assert r % BLOCK == 0 and c % BLOCK == 0
     tr = min(tile_r, r)

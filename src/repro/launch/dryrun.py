@@ -1,13 +1,12 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape) on the production
 meshes and derive the roofline terms (assignment §MULTI-POD DRY-RUN).
 
-The two lines above MUST stay the first statements in this file — jax locks
-the device count at first init, and the production meshes need 512 host
-placeholder devices. Nothing here allocates device memory: states and inputs
-are ShapeDtypeStructs, compile is ahead-of-time only.
+The production meshes need 512 host placeholder devices: `main` adds
+--xla_force_host_platform_device_count=512 to XLA_FLAGS before anything
+initializes a jax backend (jax locks the device count at first init).
+Importing this module (`benchmarks/hlo_breakdown.py` does, for
+`build_cell`) leaves XLA_FLAGS alone. Nothing here allocates device memory:
+states and inputs are ShapeDtypeStructs, compile is ahead-of-time only.
 
 Usage:
   python -m repro.launch.dryrun                          # full sweep
@@ -19,6 +18,7 @@ Artifacts: one JSON per cell under benchmarks/artifacts/dryrun/.
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -31,7 +31,6 @@ from repro.configs.base import ARCH_IDS, SHAPES, get_config
 from repro.launch.mesh import make_production_mesh
 from repro.models import api as model_api
 from repro.models.api import SkippedShape
-from repro.parallel import mesh as mesh_lib
 from repro.parallel import sharding as sh
 from repro.roofline import analysis as roofline
 from repro.serve import engine as serve_engine
@@ -191,7 +190,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool, variant: str,
     t0 = time.time()
     try:
         fn, args, in_sh, out_sh = build_cell(api, mesh, shape_name, variant)
-        with mesh_lib.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jit_kw = {"in_shardings": in_sh}
             if out_sh is not None:
                 jit_kw["out_shardings"] = out_sh
@@ -225,7 +224,13 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool, variant: str,
     return rec
 
 
+HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count=512"
+
+
 def main():
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = f"{flags} {HOST_DEVICES_FLAG}".strip()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=ARCH_IDS + [None])
     ap.add_argument("--shape", default=None, choices=list(SHAPES) + [None])

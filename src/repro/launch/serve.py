@@ -32,6 +32,7 @@ from repro.configs.base import ARCH_IDS, get_config
 from repro.models import api as model_api
 from repro.parallel import mesh as mesh_lib
 from repro.parallel import sharding as sh
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import engine as E
 
 
@@ -128,6 +129,7 @@ def main(argv=None):
                     help="draw prompt lengths/budgets per request (shows the "
                          "slot scheduler retiring and re-admitting)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

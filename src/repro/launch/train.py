@@ -27,6 +27,7 @@ from repro.models import api as model_api
 from repro.optim.adamw import AdamWConfig
 from repro.parallel import mesh as mesh_lib
 from repro.runtime import fault
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.train import step as train_step
 
 
@@ -83,6 +84,7 @@ def main(argv=None):
     ap.add_argument("--model-par", type=int, default=1)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -94,7 +96,7 @@ def main(argv=None):
 
     n_dev = len(jax.devices())
     mp = args.model_par
-    mesh = jax.make_mesh((max(n_dev // mp, 1), mp), ("data", "model"))
+    mesh = mesh_lib.make_mesh((max(n_dev // mp, 1), mp), ("data", "model"))
     tc = train_step.TrainConfig(
         microbatches=args.microbatches,
         remat=args.remat,
@@ -120,7 +122,7 @@ def main(argv=None):
         start = 0
 
     batches = make_batch_fn(api, args.seq, args.batch)
-    with mesh_lib.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn = train_step.jit_train_step(api, mesh, tc, state, batches(0))
 
         monitor = fault.StragglerMonitor()

@@ -405,8 +405,10 @@ def mla_decode_attention(p, x, positions, cfg, c_kv, k_rope, pos):
     valid = jnp.arange(skv)[None] <= posv[:, None]       # (B, S) per-row horizon
     sc = jnp.where(valid[:, None, None], sc, -jnp.inf)
     prob = jax.nn.softmax(sc, axis=-1)
-    o_lat = jnp.einsum("bhqs,bsr->bqhr", prob.astype(c_kv.dtype), c_kv,
-                       preferred_element_type=jnp.float32)
+    # contract in score layout, then move heads: XLA:CPU has no bf16 dot
+    # kernel that writes the (b, q, h, r) layout directly
+    o_lat = jnp.einsum("bhqs,bsr->bhqr", prob.astype(c_kv.dtype), c_kv,
+                       preferred_element_type=jnp.float32).transpose(0, 2, 1, 3)
     out = jnp.einsum("bqhr,rhv->bqhv", o_lat.astype(wv.dtype), wv,
                      preferred_element_type=jnp.float32)
     return dense(p["wo"], out.reshape(b, s, h * vh).astype(x.dtype))

@@ -14,7 +14,7 @@ Production meshes (assignment): 16x16 = 256 chips single pod;
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # Batch axes: everything data-parallel (pod is DP too, just over slow links).
 BATCH_AXES = ("pod", "data")
@@ -22,56 +22,31 @@ MODEL_AXIS = "model"
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    return jax.make_mesh(shape, axes)
+    """The one mesh constructor: every axis `AxisType.Auto`.
 
-
-def use_mesh(mesh: Mesh):
-    """Context manager installing `mesh` as the ambient mesh.
-
-    `jax.set_mesh` only exists on newer jax; on the pinned 0.4.x a `Mesh`
-    is itself the legacy global-mesh context manager with the same effect
-    for jit + NamedSharding use here.
+    The model, cache and serve code place tensors with sharding HINTS
+    (`parallel.sharding.logical` / `attn_hint` / `constrain`) and leave the
+    rest to GSPMD propagation.  `jax.make_mesh`'s default axis type is
+    Explicit, under which those hints turn into type assertions (and an
+    embedding gather whose operand and indices disagree raises a
+    `ShardingTypeError`), so no mesh in the repo is built any other way.
+    Uses the first prod(shape) devices, so a 2x2 mesh can sit next to a
+    4x1 one on a 4-device host.  Activate one with `jax.set_mesh(mesh)`.
     """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, axis_names=None,
-              check_vma: bool = False):
-    """`jax.shard_map` compat shim (same pattern as `use_mesh`).
-
-    Newer jax exposes shard_map at the top level with `axis_names=` (manual
-    axes) and `check_vma=`; the pinned 0.4.x only has
-    `jax.experimental.shard_map.shard_map` with the inverse `auto=` (axes
-    left to GSPMD) and `check_rep=`. Every caller (GradCompress pod
-    exchange, its tests) goes through here so both jax lines compile.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names) \
-        if axis_names is not None else frozenset()
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1) -> Mesh:
     """A mesh over whatever devices exist (tests / single-host examples)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def parse_mesh_spec(spec: str) -> tuple[int, int]:
@@ -88,9 +63,10 @@ def parse_mesh_spec(spec: str) -> tuple[int, int]:
 def make_serve_mesh(spec: str | None) -> Mesh | None:
     """Host mesh for serving from a 'DATAxMODEL' spec; None/'' => no mesh.
 
-    Uses the first data*model local devices, so a '2x2' engine can run on a
-    4-device host next to a '4x1' one in the same process (tests do exactly
-    that under --xla_force_host_platform_device_count).
+    Uses the first data*model local devices (`make_mesh`), so a '2x2'
+    engine can run on a 4-device host next to a '4x1' one in the same
+    process (tests do exactly that under
+    --xla_force_host_platform_device_count).
     """
     if not spec:
         return None
@@ -98,7 +74,7 @@ def make_serve_mesh(spec: str | None) -> Mesh | None:
     n = len(jax.devices())
     if data * model > n:
         raise ValueError(f"mesh {spec} needs {data * model} devices, have {n}")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def mesh_axis_size(mesh: Mesh, name: str) -> int:

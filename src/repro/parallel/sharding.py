@@ -234,31 +234,43 @@ def latent_cache_spec(mesh_axes: tuple[str, ...], *, stacked: bool = True) -> P:
 
 
 def ambient_mesh():
-    """The mesh whose axes sharding hints may name, or None.
+    """The mesh installed with `jax.set_mesh` — the one whose axes sharding
+    hints may name — or None when no mesh is set."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
 
-    Newer jax exposes it via `jax.sharding.get_abstract_mesh()` (set by
-    `jax.set_mesh`); the pinned 0.4.x has neither, but the legacy
-    `with mesh:` context installs a global physical mesh readable through
-    `pxla.thread_resources`.  Without this fallback every hint in the model
-    and cache code silently no-ops on 0.4.x — decode sharding would then
-    rest entirely on GSPMD propagation from the jit boundary."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and mesh.axis_names:
-            return mesh
-    except AttributeError:
-        pass
-    except Exception:
+
+def _free_axes(mesh) -> tuple[str, ...]:
+    """Mesh axes not already manual (shard_map) at this point of the trace."""
+    manual = set(mesh.manual_axes)
+    return tuple(a for a in mesh.axis_names if a not in manual)
+
+
+def kernel_rows_axes(n_rows: int) -> tuple[str, ...] | None:
+    """Mesh axes a kernel call may split `n_rows` independent rows over:
+    every free ambient axis when their product divides `n_rows`, else None
+    (the call runs replicated)."""
+    mesh = ambient_mesh()
+    if mesh is None:
         return None
-    try:
-        from jax.interpreters import pxla
+    axes = _free_axes(mesh)
+    size = int(np.prod([mesh.shape[a] for a in axes]))
+    return axes if axes and n_rows % size == 0 else None
 
-        mesh = pxla.thread_resources.env.physical_mesh
-        if not mesh.empty:
-            return mesh
-    except Exception:
-        pass
-    return None
+
+def kernel_map(fn, in_specs, out_specs):
+    """`fn` (a Pallas kernel call) made runnable under the ambient mesh.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so under a mesh the call runs per device
+    through `jax.shard_map` over every free axis, with the given specs;
+    operands arrive resharded to them.  Without a mesh `fn` is returned
+    unchanged."""
+    mesh = ambient_mesh()
+    if mesh is None or not _free_axes(mesh):
+        return fn
+    return jax.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=set(_free_axes(mesh)), check_vma=False)
 
 
 def attn_hint(x: jax.Array, *, s_axis: int = 1, h_axis: int = 2) -> jax.Array:
@@ -267,13 +279,10 @@ def attn_hint(x: jax.Array, *, s_axis: int = 1, h_axis: int = 2) -> jax.Array:
     works for any head count, e.g. qwen2's 14 or whisper's 8 heads; K/V get
     all-gathered per block, which is cheap next to score-sized partial-sum
     all-reduces GSPMD otherwise invents)."""
-    try:
-        mesh = ambient_mesh()
-        if mesh is None or "model" not in mesh.axis_names:
-            return x
-        msize = mesh.shape["model"]
-    except Exception:
+    mesh = ambient_mesh()
+    if mesh is None or "model" not in mesh.axis_names:
         return x
+    msize = mesh.shape["model"]
     entries = ["batch"] + [None] * (x.ndim - 1)
     if x.shape[h_axis] % msize == 0 and x.shape[h_axis] >= msize:
         entries[h_axis] = "model"
@@ -428,13 +437,9 @@ def per_device_bytes(shapes: Any, specs: Any, mesh: Mesh) -> float:
 def constrain(x: jax.Array, spec: P) -> jax.Array:
     """with_sharding_constraint that no-ops when no mesh context is set
     (keeps single-device unit tests independent of distribution)."""
-    try:
-        mesh = ambient_mesh()
-        if mesh is None or not mesh.axis_names:
-            return x
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
+    if ambient_mesh() is None:
         return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def logical(x: jax.Array, *entries) -> jax.Array:
@@ -448,19 +453,12 @@ def logical(x: jax.Array, *entries) -> jax.Array:
     This is the single hook every model layer uses — the hillclimb loop
     changes WHERE these are placed, not the models themselves.
     """
-    try:
-        mesh = ambient_mesh()
-        if mesh is None or not mesh.axis_names:
-            return x
-        names = set(mesh.axis_names)
-        # inside a partial-manual shard_map (GradCompress pod exchange) the
-        # manual axes must not appear in constraints — they're implicit
-        try:
-            names -= set(mesh.manual_axes)
-        except AttributeError:
-            pass
-    except Exception:
+    mesh = ambient_mesh()
+    if mesh is None:
         return x
+    # inside a partial-manual shard_map (GradCompress pod exchange) the
+    # manual axes must not appear in constraints — they're implicit
+    names = set(mesh.axis_names) - set(mesh.manual_axes)
     shape = x.shape
     out = []
     for i, e in enumerate(entries):

@@ -1,11 +1,13 @@
 """Roofline analysis from compiled dry-run artifacts (no real hardware).
 
 Three terms per (arch x shape x mesh), all in SECONDS per step, derived from
-the post-SPMD per-device module:
+the post-SPMD per-device module and the target chip's peaks (`CHIP_PEAKS`,
+keyed by `jax.Device.device_kind`; v5e: 197 TF/s bf16, 819 GB/s HBM,
+50 GB/s per ICI link):
 
-  compute    = HLO_FLOPs / PEAK_FLOPS            (197 TF/s bf16, v5e)
-  memory     = HLO_bytes / HBM_BW                (819 GB/s)
-  collective = wire_bytes / ICI_BW               (~50 GB/s/link)
+  compute    = HLO_FLOPs / peak FLOP/s
+  memory     = HLO_bytes / HBM bandwidth
+  collective = wire_bytes / ICI link bandwidth
 
 Sources: `compiled.cost_analysis()` supplies per-device FLOPs and bytes
 (the compiled module is the per-device SPMD program). Collective bytes are
@@ -33,10 +35,35 @@ import json
 import re
 from dataclasses import asdict, dataclass, field
 
-# TPU v5e hardware constants (assignment-specified)
-PEAK_FLOPS = 197e12      # bf16 FLOP/s per chip
-HBM_BW = 819e9           # bytes/s per chip
-ICI_BW = 50e9            # bytes/s per link
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    flops: float      # bf16 FLOP/s per chip
+    hbm_bw: float     # HBM bytes/s per chip
+    ici_bw: float     # ICI bytes/s per link
+    source: str
+
+
+# keyed by jax.Device.device_kind (a v5e reports "TPU v5 lite"); a device
+# missing here is an error, never a default — a roofline share against the
+# wrong chip's peaks is meaningless
+CHIP_PEAKS = {"TPU v5 lite": ChipPeaks(
+    flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+    source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+           "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip (4 links)")}
+# the chip the dry-run and kernel models target (its `device_kind`)
+TARGET_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of the chip `device_kind` names; KeyError for any other."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind {device_kind!r}; "
+                       f"known: {sorted(CHIP_PEAKS)}") from None
+
+
 # Per-grid-step DMA latency a double-buffered pallas pipeline cannot hide
 # when each step's tiles are tiny (a paged-attend page is ~100s of bytes):
 # issue + descriptor + HBM round-trip tail, ~0.5us. A kernel whose grid has
@@ -111,6 +138,7 @@ class Roofline:
     shape: str
     mesh: str
     chips: int
+    device_kind: str                 # key into CHIP_PEAKS
     hlo_flops: float                 # per device
     hlo_bytes: float                 # per device
     wire_bytes: float                # per device
@@ -119,16 +147,20 @@ class Roofline:
     memory_stats: dict = field(default_factory=dict)
 
     @property
+    def peaks(self) -> ChipPeaks:
+        return chip_peaks(self.device_kind)
+
+    @property
     def compute_s(self) -> float:
-        return self.hlo_flops / PEAK_FLOPS
+        return self.hlo_flops / self.peaks.flops
 
     @property
     def memory_s(self) -> float:
-        return self.hlo_bytes / HBM_BW
+        return self.hlo_bytes / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.wire_bytes / ICI_BW
+        return self.wire_bytes / self.peaks.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -156,7 +188,8 @@ class Roofline:
     def mfu_bound(self) -> float:
         """Model FLOPs utilization IF the step ran at bound_s."""
         per_dev = self.model_flops_global / max(self.chips, 1)
-        return per_dev / (self.bound_s * PEAK_FLOPS) if self.bound_s else 0.0
+        return per_dev / (self.bound_s * self.peaks.flops) \
+            if self.bound_s else 0.0
 
     def to_dict(self) -> dict:
         d = asdict(self)
@@ -172,7 +205,8 @@ class Roofline:
 
 def hbm_bandwidth_row(bytes_per_step: float, compute_flops: float = 0.0,
                       grid_steps: float = 0.0,
-                      mxu_efficiency: float = 1.0) -> dict:
+                      mxu_efficiency: float = 1.0, *,
+                      device_kind: str) -> dict:
     """Achieved vs peak HBM bandwidth for one (memory-streaming) step.
 
     `bytes_per_step` is what the kernel ACTUALLY streams (for attend_paged:
@@ -186,11 +220,13 @@ def hbm_bandwidth_row(bytes_per_step: float, compute_flops: float = 0.0,
     `grid_steps` charges PAGE_DMA_LATENCY_S per pallas grid step — the
     un-hideable tail of a tiny-tile double-buffered pipeline (0 = dense
     streaming kernel, latency folded into bandwidth). `mxu_efficiency`
-    derates PEAK_FLOPS for tiles narrower than the 128-lane contraction
-    (a one-page tile runs 8/128 of the MXU).
+    derates the peak FLOP/s for tiles narrower than the 128-lane
+    contraction (a one-page tile runs 8/128 of the MXU).  Peaks come from
+    `chip_peaks(device_kind)`.
     """
-    mem_s = bytes_per_step / HBM_BW
-    comp_s = compute_flops / (PEAK_FLOPS * max(mxu_efficiency, 1e-9))
+    pk = chip_peaks(device_kind)
+    mem_s = bytes_per_step / pk.hbm_bw
+    comp_s = compute_flops / (pk.flops * max(mxu_efficiency, 1e-9))
     lat_s = grid_steps * PAGE_DMA_LATENCY_S
     step_s = max(mem_s, comp_s, lat_s)
     achieved = bytes_per_step / step_s if step_s else 0.0
@@ -202,8 +238,8 @@ def hbm_bandwidth_row(bytes_per_step: float, compute_flops: float = 0.0,
         "grid_latency_s": lat_s,
         "grid_steps": float(grid_steps),
         "achieved_bw_gbs": achieved / 1e9,
-        "peak_bw_gbs": HBM_BW / 1e9,
-        "hbm_utilization": achieved / HBM_BW,
+        "peak_bw_gbs": pk.hbm_bw / 1e9,
+        "hbm_utilization": achieved / pk.hbm_bw,
     }
 
 
@@ -227,7 +263,7 @@ def model_flops(cfg, shape_name: str, n_layers_factor: float = 1.0) -> float:
 
 
 def from_compiled(arch: str, shape: str, mesh_name: str, chips: int,
-                  compiled, cfg) -> Roofline:
+                  compiled, cfg, device_kind: str = TARGET_KIND) -> Roofline:
     """Derive the three terms from the compiled per-device module.
 
     FLOPs/bytes/wire come from the trip-count-aware HLO analyzer
@@ -255,7 +291,7 @@ def from_compiled(arch: str, shape: str, mesh_name: str, chips: int,
     }
     return Roofline(
         arch=arch, shape=shape, mesh=mesh_name, chips=chips,
-        hlo_flops=st.flops, hlo_bytes=st.bytes, wire_bytes=st.wire,
+        device_kind=device_kind, hlo_flops=st.flops, hlo_bytes=st.bytes, wire_bytes=st.wire,
         model_flops_global=model_flops(cfg, shape),
         collectives=st.coll, memory_stats=mem,
     )

@@ -66,7 +66,6 @@ from repro.core import kv_cache as kvc
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.api import ModelAPI
-from repro.parallel import mesh as mesh_lib
 from repro.parallel import sharding as sh
 from repro.serve import pipeline as pl
 from repro.serve import tiering
@@ -136,11 +135,15 @@ def decode_step_compressed(
         assert flush_page is not None, "paged decode needs the flush_page vector"
         nblocks = cache.block_table.shape[1]
         rows = jnp.arange(b_sz)
-        flush_row = jnp.mod(pos, kvc.BLOCK) == kvc.BLOCK - 1
-        # non-flushing rows are gated by blk=nblocks here (drop) and by
-        # update_layer's own flush_row gate on the pool scatter — stray
-        # page ids for such rows can land nowhere
         fp = kvc.as_pos_vec(flush_page, b_sz)
+        # a row flushes when its tail ring fills AND the engine handed it a
+        # page: a speculative step past a slot's reserved horizon (or a
+        # retired row) reaches pos%8==7 with the out-of-range id, which
+        # must not enter the table the attend kernel DMAs pages through.
+        # Non-flushing rows are gated by blk=nblocks here (drop) and by
+        # update_layer's own gate on the pool scatter.
+        flush_row = (jnp.mod(pos, kvc.BLOCK) == kvc.BLOCK - 1) \
+            & (fp >= 0) & (fp < cache.n_pages)
         blk = jnp.where(flush_row, pos // kvc.BLOCK, nblocks)
         block_table = cache.block_table.at[rows, blk].set(fp, mode="drop")
         block_table = sh.logical(block_table, "batch", None)
@@ -765,6 +768,7 @@ class Engine:
         if sc.prefix_sharing:
             self._prefix = tiering.PrefixIndex()
         self._cache_init_raw = cache_init  # un-jitted: pool accounting
+        self._vec_sharding = None  # (B,) token/pos placement under a mesh
         self.trace_counts = pl.TraceCounts()
         tc = self.trace_counts
         if self.scheduler == "continuous":
@@ -813,6 +817,7 @@ class Engine:
                         pl.counting("match", tc, kvc.paged_rows_match))
             else:
                 shd = serve_shardings(api, params, sc, batch, cache_init)
+                self._vec_sharding = shd["vec"]
                 # place params once; the decode jit pins the same shardings,
                 # so no per-call retransfer
                 params = jax.device_put(params, shd["params"])
@@ -917,12 +922,23 @@ class Engine:
         self._worker = None
         self._t_gen0 = 0.0
         if sc.aot_warmup and self.scheduler == "continuous":
-            ctx = mesh_lib.use_mesh(sc.mesh) if sc.mesh is not None \
+            ctx = jax.set_mesh(sc.mesh) if sc.mesh is not None \
                 else contextlib.nullcontext()
             with ctx:
                 self.stats["warmup_s"] += pl.warmup_engine(self)
 
     # ------------------------------------------------------------------ util
+    def device_vec(self, host: np.ndarray) -> jax.Array:
+        """A host (B,) token/position vector placed as the decode and fix
+        jits emit theirs: committed on the mesh's data axes under a mesh,
+        uncommitted on one device otherwise.  Warmup and traffic then hand
+        those jits identically placed arguments, so the warmed executables
+        are reused (a jnp constructor under `jax.set_mesh` would commit it
+        replicated, which a jit pinned to the data axes refuses)."""
+        if self._vec_sharding is None:
+            return jnp.asarray(host)
+        return jax.device_put(host, self._vec_sharding)
+
     def _sample(self, logits: jax.Array) -> jax.Array:
         if self.sc.temperature <= 0.0:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1063,7 +1079,7 @@ class Engine:
         # the ambient mesh context activates the model-internal shard hints
         # (sharding.logical / attn_hint) while the jits' explicit in/out
         # NamedShardings pin the step boundaries
-        ctx = mesh_lib.use_mesh(self.sc.mesh) if self.sc.mesh is not None \
+        ctx = jax.set_mesh(self.sc.mesh) if self.sc.mesh is not None \
             else contextlib.nullcontext()
         with ctx:
             if self.scheduler == "static":
@@ -1669,8 +1685,8 @@ class Engine:
         self._devpos = np.zeros(b, np.int64)   # device pos mirror (see _dispatch)
         self._last_emit = np.zeros(b)
         self._last_tok = np.zeros(b, np.int64)  # last emitted token per slot
-        self._tok_dev = jnp.zeros((b,), jnp.int32)
-        self._pos_dev = jnp.zeros((b,), jnp.int32)
+        self._tok_dev = self.device_vec(np.zeros((b,), np.int32))
+        self._pos_dev = self.device_vec(np.zeros((b,), np.int32))
         self._staged = []
         self._qi = 0
         cache = self._cache_init(b)

@@ -277,20 +277,23 @@ def warmup_engine(engine) -> float:
     temp = engine.sc.temperature > 0.0
     rng = jax.random.PRNGKey(0)  # warmup never touches the engine's stream
     cache = engine._cache_init(engine.batch)
-    zeros_b = jnp.zeros((engine.batch,), jnp.int32)
+    # host-built inputs, placed as the serve loop places its own: under
+    # `jax.set_mesh` a jnp constructor would commit them replicated, which
+    # the decode/fix jits pinned to the mesh's data axes refuse
+    zeros_b = engine.device_vec(np.zeros((engine.batch,), np.int32))
     ladder = engine.ladder
     nb_table = engine.sc.max_seq // BLOCK
     for bucket in ladder.buckets:
         for rows in ladder.row_counts(engine.batch):
-            tokens = jnp.zeros((rows, bucket), jnp.int32)
-            lengths = jnp.full((rows,), bucket, jnp.int32)
+            tokens = jnp.asarray(np.zeros((rows, bucket), np.int32))
+            lengths = jnp.asarray(np.full((rows,), bucket, np.int32))
             args = [engine.params, tokens, lengths] + ([rng] if temp else [])
             first, slot_cache = engine._admit_step(*args)
-            drop_slots = jnp.full((rows,), engine.batch, jnp.int32)
+            drop_slots = jnp.asarray(np.full((rows,), engine.batch, np.int32))
             if engine.paged:
-                page_ids = jnp.full((rows, bucket // BLOCK), engine._n_pages,
-                                    jnp.int32)
-                table_rows = jnp.zeros((rows, nb_table), jnp.int32)
+                page_ids = jnp.asarray(np.full((rows, bucket // BLOCK),
+                                               engine._n_pages, np.int32))
+                table_rows = jnp.asarray(np.zeros((rows, nb_table), np.int32))
                 cache = engine._write(cache, slot_cache, drop_slots,
                                       page_ids, table_rows)
             else:
@@ -305,7 +308,8 @@ def warmup_engine(engine) -> float:
     # dense engine has a single decode shape.
     step_args = [engine.params, zeros_b, cache, zeros_b]
     if engine.paged:
-        step_args.append(jnp.full((engine.batch,), engine._n_pages, jnp.int32))
+        step_args.append(jnp.asarray(
+            np.full((engine.batch,), engine._n_pages, np.int32)))
     if temp:
         step_args.append(rng)
     if engine.paged:
@@ -329,8 +333,9 @@ def warmup_engine(engine) -> float:
                 cache, upd, jnp.int32(0), jnp.asarray(ids),
                 jnp.asarray(np.zeros((nb_table,), np.int32)))
     cache = engine._reset(cache, jnp.int32(0))
-    drop_idx = jnp.full((engine.batch,), engine.batch, jnp.int32)
-    tok, pos1 = engine._fix(tok, pos1, drop_idx, zeros_b, zeros_b)
+    drop_idx = jnp.asarray(np.full((engine.batch,), engine.batch, np.int32))
+    fix_vals = jnp.asarray(np.zeros((engine.batch,), np.int32))
+    tok, pos1 = engine._fix(tok, pos1, drop_idx, fix_vals, fix_vals)
     tok.block_until_ready()
     del cache
     np.asarray(tok)  # drain

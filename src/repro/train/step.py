@@ -29,7 +29,6 @@ from repro.codec import plan as plan_lib
 from repro.core import grad_comp
 from repro.models.api import ModelAPI
 from repro.optim import adamw
-from repro.parallel import mesh as mesh_lib
 from repro.parallel import sharding as sh
 
 Params = dict[str, Any]
@@ -168,7 +167,7 @@ def make_train_step(api: ModelAPI, mesh: Mesh, tc: TrainConfig):
             loss = jax.lax.pmean(loss, "pod")
             return grads, new_residual, loss
 
-        pod_grads = mesh_lib.shard_map(
+        pod_grads = jax.shard_map(
             per_pod,
             mesh=mesh,
             in_specs=(P(), P(), P("pod")),

@@ -31,9 +31,9 @@ def test_resume_with_new_mesh_geometry(tmp_path, setup):
     root = str(tmp_path / "ck")
 
     # phase 1: "old fleet"
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh1 = mesh_lib.make_mesh((1, 1), ("data", "model"))
     state = train_step.init_train_state(api, tc)
-    with mesh_lib.use_mesh(mesh1):
+    with jax.set_mesh(mesh1):
         step1 = jax.jit(train_step.make_train_step(api, mesh1, tc))
         for i in range(4):
             b = {k: jnp.asarray(v) for k, v in ts.batch(i).items()}
@@ -42,7 +42,7 @@ def test_resume_with_new_mesh_geometry(tmp_path, setup):
 
     # phase 2: "replacement fleet" with a different (degenerate) geometry +
     # restore re-sharded onto the new mesh via explicit shardings
-    mesh2 = jax.make_mesh((1,), ("data",))
+    mesh2 = mesh_lib.make_mesh((1,), ("data",))
     like = jax.eval_shape(lambda: train_step.init_train_state(api, tc))
     sspec = train_step.state_specs(like, mesh2, tc)
     shardings = jax.tree.map(
@@ -52,7 +52,7 @@ def test_resume_with_new_mesh_geometry(tmp_path, setup):
     assert int(restored["step"]) == 4
 
     losses = []
-    with mesh_lib.use_mesh(mesh2):
+    with jax.set_mesh(mesh2):
         step2 = jax.jit(train_step.make_train_step(api, mesh2, tc))
         for i in range(4, 12):
             b = {k: jnp.asarray(v) for k, v in ts.batch(i).items()}

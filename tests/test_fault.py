@@ -1,16 +1,19 @@
-"""Fault-tolerance runtime tests: preemption, stragglers, heartbeat,
-checkpoint retention/commit protocol, elastic data replay."""
+"""Runtime tests: preemption, stragglers, heartbeat, checkpoint
+retention/commit protocol, elastic data replay, compile-cache location."""
 import os
 import signal
 import threading
 import time
+from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.ckpt import store
 from repro.data.synthetic import TokenStream
+from repro.runtime import compile_cache as cc
 from repro.runtime import fault
 
 
@@ -105,3 +108,32 @@ def test_elastic_data_replay():
         assert len({p.tobytes() for p in parts}) == dp
     plan = fault.ElasticPlan(resume_step=5, old_dp=2, new_dp=4)
     assert plan.shard_for(6) == (2, 4)
+
+
+# ---------------------------------------------------------------------------
+# Persistent compile cache location
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def cache_dir_option():
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_uses_env_dir_and_sets_nothing(monkeypatch,
+                                                     cache_dir_option):
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(cc.ENV, "/cache/from/env")
+    assert cc.enable_compile_cache() == "/cache/from/env"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_fixed_ignored_dir(monkeypatch,
+                                                     cache_dir_option):
+    monkeypatch.delenv(cc.ENV, raising=False)
+    got = cc.enable_compile_cache()
+    root = Path(__file__).resolve().parents[1]
+    assert got == str(root / ".jax_cache") == cc.enable_compile_cache()
+    assert jax.config.jax_compilation_cache_dir == got
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()

@@ -65,14 +65,13 @@ def test_error_feedback_topk_converges_corner_diverges():
 def test_exchange_compressed_under_shard_map():
     """2-pod exchange: both pods receive the mean of the per-pod grads.
 
-    Goes through the parallel.mesh.shard_map compat shim so it runs on the
-    pinned 0.4.x (jax.experimental.shard_map) and newer jax alike — the CI
-    multidevice job exercises this case under 4 forced host devices."""
+    The CI multidevice job exercises this case under 4 forced host
+    devices."""
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices (run under XLA_FLAGS device_count)")
     from repro.parallel import mesh as mesh_lib
 
-    mesh = jax.make_mesh((2,), ("pod",))
+    mesh = mesh_lib.make_mesh((2,), ("pod",))
     grads = {"w": jnp.stack([jnp.ones((16, 16)), 3 * jnp.ones((16, 16))])}
     residual = {"w": jnp.zeros((16, 16))}
     cfg = GC.GradCompressConfig(keep=8)
@@ -83,7 +82,7 @@ def test_exchange_compressed_under_shard_map():
 
     from jax.sharding import PartitionSpec as P
     g_local = {"w": grads["w"].reshape(32, 16)}  # (2*16, 16) sharded over pod
-    fn = mesh_lib.shard_map(
+    fn = jax.shard_map(
         lambda g, r: f({"w": g["w"]}, r),
         mesh=mesh, in_specs=({"w": P("pod")}, {"w": P()}),
         out_specs=({"w": P("pod")}, {"w": P()}), axis_names={"pod"},

@@ -169,6 +169,27 @@ def test_pool_exhaustion_blocks_admission_and_reuses_pages(lm):
     assert sorted(eng._free_pages) == list(range(4))  # all returned at drain
 
 
+def test_unreserved_flush_never_enters_block_table(lm):
+    """A row reaching pos%8==7 without a reserved page (a speculative step
+    past its horizon, or a retired row) gets the out-of-range id: the
+    decode step must leave its table entry alone, since the compiled
+    attend kernel DMAs pages through the table. A row with a page maps it."""
+    api, params = lm
+    b, max_seq, n_pages = 3, 64, 5
+    cache = KV.init_paged_cache(api.cfg, b, max_seq, n_pages, keep=8,
+                                dtype=jnp.float32)
+    pos = jnp.asarray([7, 15, 3], jnp.int32)   # rows 0, 1 fill their tail
+    fp = jnp.asarray([n_pages, 2, n_pages], jnp.int32)
+    _, out = E.decode_step_compressed(params, jnp.zeros((b,), jnp.int32),
+                                      cache, pos, api.cfg,
+                                      codec_backend="reference",
+                                      flush_page=fp)
+    table = np.asarray(out.block_table)
+    assert table.min() >= 0 and table.max() < n_pages, table
+    assert table[1, 1] == 2 and table[0, 0] == 0
+    assert np.count_nonzero(table) == 1
+
+
 def test_request_larger_than_pool_raises(lm):
     api, params = lm
     eng = E.Engine(api, params,

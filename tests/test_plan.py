@@ -11,6 +11,7 @@ from repro.codec.plan import BLOCK, CompressionPlan, LayerPolicy, as_plan
 from repro.core import kv_cache as KV
 from repro.data.synthetic import TokenStream
 from repro.models import api as model_api
+from repro.parallel import mesh as mesh_lib
 from repro.serve import engine as E
 
 
@@ -239,7 +240,7 @@ def trained_lm():
         optimizer=AdamWConfig(lr=3e-3, warmup_steps=3, total_steps=60))
     state = train_step.init_train_state(api, tc)
     step = jax.jit(train_step.make_train_step(
-        api, jax.make_mesh((1,), ("data",)), tc), donate_argnums=(0,))
+        api, mesh_lib.make_mesh((1,), ("data",)), tc), donate_argnums=(0,))
     for i in range(40):
         b = {k: jnp.asarray(v) for k, v in ts.batch(i).items()}
         state, _ = step(state, b)

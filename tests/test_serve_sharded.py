@@ -170,10 +170,10 @@ def test_decode_hlo_has_no_full_cache_all_gather(lm):
     sc = E.ServeConfig(max_seq=64, kv_compress=True, kv_keep=8,
                        codec_backend="reference", mesh=mesh)
     eng = E.Engine(api, params, sc, batch=4)
-    with mesh_lib.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         cache = eng._cache_init(4)
-        args = (eng.params, jnp.zeros((4,), jnp.int32), cache,
-                jnp.zeros((4,), jnp.int32))
+        zeros = eng.device_vec(np.zeros((4,), np.int32))
+        args = (eng.params, zeros, cache, zeros)
         txt = eng._decode.lower(*args).compile().as_text()
     # one segment: packed_k (L, B, ns, Hkv, hd/8, k, k) int8 — the smallest
     # full-cache plane anything could gather
@@ -198,10 +198,10 @@ def test_decode_io_shardings_are_explicit(lm):
     sc = E.ServeConfig(max_seq=64, kv_compress=True, kv_keep=8,
                        codec_backend="reference", mesh=mesh)
     eng = E.Engine(api, params, sc, batch=4)
-    with mesh_lib.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         cache = eng._cache_init(4)
-        tok, pos1, cache2 = eng._decode(eng.params, jnp.zeros((4,), jnp.int32),
-                                        cache, jnp.zeros((4,), jnp.int32))
+        zeros = eng.device_vec(np.zeros((4,), np.int32))
+        tok, pos1, cache2 = eng._decode(eng.params, zeros, cache, zeros)
     def batch_axis(arr):
         return arr.sharding.spec[1]
     for segment in cache2.segments:

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.parallel import mesh as mesh_lib
 from repro.parallel import sharding as sh
 from repro.roofline import analysis as RA
 from repro.roofline import hlo as H
@@ -12,7 +13,7 @@ from repro.roofline import hlo as H
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return mesh_lib.make_mesh((1, 1), ("data", "model"))
 
 
 def test_param_spec_rules(mesh):
@@ -44,7 +45,7 @@ def test_param_spec_rules(mesh):
 
 
 def test_fit_spec_divisibility():
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model")) \
+    mesh = mesh_lib.make_mesh((2, 2, 2), ("pod", "data", "model")) \
         if len(jax.devices()) >= 8 else None
     if mesh is None:
         pytest.skip("needs 8 devices")
@@ -99,8 +100,6 @@ def test_per_device_bytes_counts_shard_factors(mesh):
 
 
 def test_make_serve_mesh_spec_parsing():
-    from repro.parallel import mesh as mesh_lib
-
     assert mesh_lib.parse_mesh_spec("4x1") == (4, 1)
     assert mesh_lib.parse_mesh_spec("2X2") == (2, 2)
     assert mesh_lib.make_serve_mesh(None) is None
@@ -187,7 +186,7 @@ ENTRY %main (a: f32[64,64]) -> f32[64,64] {
 def test_roofline_terms_and_dominance():
     r = RA.Roofline(
         arch="x", shape="train_4k", mesh="16x16", chips=256,
-        hlo_flops=197e12, hlo_bytes=819e9 * 2, wire_bytes=50e9 * 0.5,
+        device_kind="TPU v5 lite", hlo_flops=197e12, hlo_bytes=819e9 * 2, wire_bytes=50e9 * 0.5,
         model_flops_global=197e12 * 256 * 0.5,
     )
     assert r.compute_s == pytest.approx(1.0)
@@ -196,6 +195,17 @@ def test_roofline_terms_and_dominance():
     assert r.dominant == "memory"
     assert r.roofline_fraction == pytest.approx(2.0 / 3.5)
     assert r.useful_flop_ratio == pytest.approx(0.5)
+
+
+def test_roofline_peaks_are_keyed_by_device_kind():
+    assert RA.chip_peaks("TPU v5 lite").hbm_bw == 819e9
+    r = RA.Roofline(arch="x", shape="train_4k", mesh="1", chips=1,
+                    device_kind="cpu", hlo_flops=1.0, hlo_bytes=1.0,
+                    wire_bytes=0.0, model_flops_global=1.0)
+    with pytest.raises(KeyError, match="cpu"):
+        r.compute_s
+    with pytest.raises(KeyError, match="cpu"):
+        RA.hbm_bandwidth_row(1.0, device_kind="cpu")
 
 
 def test_model_flops_kinds():

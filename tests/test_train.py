@@ -11,6 +11,7 @@ from repro.data.synthetic import TokenStream
 from repro.models import api as model_api
 from repro.optim import adamw
 from repro.optim.adamw import AdamWConfig
+from repro.parallel import mesh as mesh_lib
 from repro.train import step as train_step
 
 
@@ -23,7 +24,7 @@ def setup():
 
 def _run(api, ts, tc, steps=20):
     state = train_step.init_train_state(api, tc)
-    step = jax.jit(train_step.make_train_step(api, jax.make_mesh((1,), ("data",)), tc),
+    step = jax.jit(train_step.make_train_step(api, mesh_lib.make_mesh((1,), ("data",)), tc),
                    donate_argnums=(0,))
     losses = []
     for i in range(steps):
@@ -98,7 +99,7 @@ def test_microbatch_equivalence(setup):
     """1 vs 4 microbatches give identical grads (up to f32 reassociation)."""
     api, ts = setup
     batch = {k: jnp.asarray(v) for k, v in ts.batch(0).items()}
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = mesh_lib.make_mesh((1,), ("data",))
     outs = []
     for n in (1, 4):
         tc = train_step.TrainConfig(microbatches=n, remat="none")
@@ -129,7 +130,7 @@ def test_checkpoint_resume_exact(tmp_path, setup):
         microbatches=1, remat="full",
         optimizer=AdamWConfig(lr=1e-3, warmup_steps=2, total_steps=30),
     )
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = mesh_lib.make_mesh((1,), ("data",))
     step = jax.jit(train_step.make_train_step(api, mesh, tc))
 
     def batch(i):
