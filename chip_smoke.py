@@ -172,13 +172,6 @@ def serve(name: str, eng, prompts) -> list[list[int]]:
     before = eng.trace_counts.snapshot()
     done = eng.generate([E.Request(uid=i, prompt=p, max_new=MAX_NEW)
                          for i, p in enumerate(prompts)])
-    st = eng.stats
-    dec_tok = st["tokens_out"] - st["requests"]
-    tps = dec_tok / st["decode_s"] if st["decode_s"] else 0.0
-    print(f"[smoke output, not a benchmark] {name}: warmup_s="
-          f"{st['warmup_s']:.2f} prefill_s={st['prefill_s']:.3f} "
-          f"decode_s={st['decode_s']:.3f} host_s={st['host_s']:.3f} "
-          f"decode_steps={st['steps']} decode tok/s={tps:.1f}", flush=True)
     check(all(len(r.out_tokens) == MAX_NEW for r in done),
           f"{name}: all {len(done)} requests returned {MAX_NEW} tokens")
     if eng.sc.aot_warmup:

@@ -407,5 +407,6 @@ def attend_paged(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, n_rep, hd), jnp.float32),
         interpret=interpret,
+        name="attend_paged",
     )(pos.astype(jnp.int32), block_table.astype(jnp.int32),
       *pages, qh, q, jnp.asarray(ckg), jnp.asarray(bde), tk, tv)

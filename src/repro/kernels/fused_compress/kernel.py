@@ -152,6 +152,7 @@ def compress_plane_pallas(
             jax.ShapeDtypeStruct((nct, rp // BLOCK, nc), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_compress",
     )(xp, *(jnp.asarray(a) for a in consts))
     nh, nw = r // BLOCK, c // BLOCK
     # (nct, k, k, R/8, nc) -> (R/8, nct*nc, k, k) blocks layout
@@ -197,5 +198,6 @@ def decompress_plane_pallas(
         out_specs=pl.BlockSpec((tr, tc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rp, cp), out_dtype),
         interpret=interpret,
+        name="fused_decompress",
     )(q, scale, *(jnp.asarray(a) for a in consts))
     return out[:r, :c]

@@ -142,11 +142,13 @@ def decode_step_compressed(
         # must not enter the table the attend kernel DMAs pages through.
         # Non-flushing rows are gated by blk=nblocks here (drop) and by
         # update_layer's own gate on the pool scatter.
-        flush_row = (jnp.mod(pos, kvc.BLOCK) == kvc.BLOCK - 1) \
-            & (fp >= 0) & (fp < cache.n_pages)
-        blk = jnp.where(flush_row, pos // kvc.BLOCK, nblocks)
-        block_table = cache.block_table.at[rows, blk].set(fp, mode="drop")
-        block_table = sh.logical(block_table, "batch", None)
+        with jax.named_scope("kv_flush"):
+            flush_row = (jnp.mod(pos, kvc.BLOCK) == kvc.BLOCK - 1) \
+                & (fp >= 0) & (fp < cache.n_pages)
+            blk = jnp.where(flush_row, pos // kvc.BLOCK, nblocks)
+            block_table = cache.block_table.at[rows, blk].set(fp,
+                                                              mode="drop")
+            block_table = sh.logical(block_table, "batch", None)
         att_table = kvc.table_view(block_table, attend_blocks)
     else:
         assert flush_page is None, "flush_page is a paged-pool argument"
@@ -162,23 +164,33 @@ def decode_step_compressed(
     def make_layer_step(keep, backend, codec):
         def layer_step(h, inp):
             p, lc = inp["p"], inp["cache"]
-            hn = norm(p["ln1"], h)
-            b, s, _ = hn.shape
-            q = L.dense(p["attn"]["wq"], hn).reshape(b, s, cfg.n_heads, hd)
-            q = sh.attn_hint(q)  # heads on `model` (matches the cache specs)
-            q = L.apply_rope(q, positions, cfg.rope_theta)
-            k_new, v_new = L.gqa_project_kv(p["attn"], hn, positions, cfg)
-            lc2 = kvc.update_layer(lc, k_new, v_new, pos, keep, backend=backend,
-                                   flush_page=fp, codec=codec)
-            attn = kvc.attend_auto(q, lc2, pos, keep, kv_block=kv_block,
-                                   backend=backend, block_table=att_table,
-                                   pages_per_tile=pages_per_tile, codec=codec)
-            attn = sh.attn_hint(attn)
-            h = h + L.dense(p["attn"]["wo"], attn.reshape(b, s, cfg.n_heads * hd))
-            if "moe" in p:
-                h = h + L.moe_ffn(p["moe"], norm(p["ln2"], h), cfg, dropless=True)
-            else:
-                h = h + L.mlp(p["mlp"], norm(p["ln2"], h), cfg)
+            with jax.named_scope("qkv"):
+                hn = norm(p["ln1"], h)
+                b, s, _ = hn.shape
+                q = L.dense(p["attn"]["wq"], hn).reshape(b, s, cfg.n_heads,
+                                                         hd)
+                q = sh.attn_hint(q)  # heads on `model` (as the cache specs)
+                q = L.apply_rope(q, positions, cfg.rope_theta)
+                k_new, v_new = L.gqa_project_kv(p["attn"], hn, positions,
+                                                cfg)
+            with jax.named_scope("kv_flush"):
+                lc2 = kvc.update_layer(lc, k_new, v_new, pos, keep,
+                                       backend=backend, flush_page=fp,
+                                       codec=codec)
+            with jax.named_scope("attend"):
+                attn = kvc.attend_auto(q, lc2, pos, keep, kv_block=kv_block,
+                                       backend=backend, block_table=att_table,
+                                       pages_per_tile=pages_per_tile,
+                                       codec=codec)
+                attn = sh.attn_hint(attn)
+                h = h + L.dense(p["attn"]["wo"],
+                                attn.reshape(b, s, cfg.n_heads * hd))
+            with jax.named_scope("mlp"):
+                if "moe" in p:
+                    h = h + L.moe_ffn(p["moe"], norm(p["ln2"], h), cfg,
+                                      dropless=True)
+                else:
+                    h = h + L.mlp(p["mlp"], norm(p["ln2"], h), cfg)
             return h, lc2
 
         return layer_step
@@ -197,13 +209,17 @@ def decode_step_compressed(
             pslice = jax.tree.map(lambda p: p[s0 - ps:s1 - ps], stack)
             cslice = jax.tree.map(lambda c: c[s0 - seg.start:s1 - seg.start],
                                   seg_tree)
-            x, nc = jax.lax.scan(layer_step, x, {"p": pslice, "cache": cslice})
+            # the scope holds the copies XLA places around the loop too
+            with jax.named_scope("layer_scan"):
+                x, nc = jax.lax.scan(layer_step, x,
+                                     {"p": pslice, "cache": cslice})
             parts.append(nc)
         new_tree = parts[0] if len(parts) == 1 else \
             jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
         new_segments.append(seg.replace_arrays(new_tree))
 
-    logits = T.unembed(params, x, cfg)[:, 0]
+    with jax.named_scope("unembed"):
+        logits = T.unembed(params, x, cfg)[:, 0]
     if paged:
         return logits, kvc.PagedKVCache(tuple(new_segments), block_table)
     return logits, kvc.CompressedKVCache(tuple(new_segments))
@@ -293,11 +309,12 @@ def prefill_compressed_paged(
     update = []
     for start, stop, pol in plan.segments(cfg.n_layers):
         kseg = pol.kv_keep
-        comp = jax.vmap(
-            lambda k, v: kvc.prefill_compress(k, v, kseg, pos=lengths,
-                                              backend=pol.backend,
-                                              codec=pol.codec)
-        )(raw["k"][start:stop], raw["v"][start:stop])  # vmap over layers
+        with jax.named_scope("kv_bulk_compress"):
+            comp = jax.vmap(
+                lambda k, v: kvc.prefill_compress(k, v, kseg, pos=lengths,
+                                                  backend=pol.backend,
+                                                  codec=pol.codec)
+            )(raw["k"][start:stop], raw["v"][start:stop])  # over layers
         comp["tail_k"] = comp["tail_k"].astype(dtype)
         comp["tail_v"] = comp["tail_v"].astype(dtype)
         update.append(comp)
@@ -533,6 +550,7 @@ def make_fused_steps(prefill_fn, decode_fn, sc: ServeConfig, *, paged: bool):
     """
     greedy = sc.temperature <= 0.0
 
+    @jax.named_scope("sample")
     def pick(logits, rng):
         if greedy:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -709,9 +727,25 @@ class Engine:
     Sampling order is explicit: the first output token is sampled from the
     prefill logits at the prompt's last position; a decode step only runs
     while some slot still needs tokens (a request whose max_new is 1
-    finishes at admission without a decode step). `stats` splits wall time
-    into warmup_s / prefill_s / decode_s / host_s; `latency_stats()`
+    finishes at admission without a decode step). `latency_stats()`
     reports p50/p99 TTFT and inter-token latency.
+
+    The serve loop is traced by host spans (`pipeline.Span`, integer
+    metadata), which land in a running profile on the device ops' clock:
+    `serve.admit` (rows, bucket) holds `serve.prefill` (rows, bucket),
+    `serve.worker_flush` and, on a tiered pool, `serve.park` (slot) /
+    `serve.resume`; `serve.dispatch` (step, live, bucket) launches decode
+    step `step`, `serve.readback` (step) blocks on it (or, inside
+    `serve.admit`, on the admission's first tokens) and `serve.process`
+    (step) applies its bookkeeping; `serve.bookkeeping` is one closure on
+    the bookkeeping thread and `python.gc` one collector pause. The time
+    counters of `stats` are sums of span durations, each added when its
+    span closes, so a window's delta can be read while `generate()` runs:
+    prefill_s = Σ serve.admit, decode_s = Σ serve.dispatch + Σ decode
+    serve.readback, wait_s = Σ serve.readback (the host blocked on the
+    device). host_s is written only when a continuous `generate()`
+    returns: the call's wall time less its decode_s and prefill_s.
+    warmup_s is the AOT warmup at construction.
 
     `scheduler="static"` restores wave-at-a-time lock-step batching
     (right-aligned prompts, one scalar position) — the baseline the
@@ -783,10 +817,14 @@ class Engine:
                                                            sc.decode_buckets)
                 # one partial per bucket: each binds its static table-slice
                 # width, so each is a distinct jit (and a distinct "decode"
-                # trace — the warmed count is len(buckets))
-                bucket_fns = {
-                    t: functools.partial(step_fn, attend_blocks=t // kvc.BLOCK)
-                    for t in self.decode_ladder.buckets}
+                # trace — the warmed count is len(buckets)); each keeps the
+                # step's name, which names its program in a profile
+                bucket_fns = {}
+                for t in self.decode_ladder.buckets:
+                    fn = functools.partial(step_fn,
+                                           attend_blocks=t // kvc.BLOCK)
+                    fn.__name__ = step_fn.__name__
+                    bucket_fns[t] = fn
             write_fn = pl.counting(
                 "write", tc,
                 kvc.paged_write_rows if self.paged else cache_write_rows)
@@ -910,7 +948,7 @@ class Engine:
         self.params = params
         self.stats = {"requests": 0, "tokens_out": 0, "steps": 0,
                       "prefill_s": 0.0, "decode_s": 0.0, "host_s": 0.0,
-                      "warmup_s": 0.0,
+                      "wait_s": 0.0, "warmup_s": 0.0,
                       "slot_steps_live": 0, "slot_steps_total": 0,
                       "peak_live_slots": 0, "admit_blocked_on_pages": 0,
                       "peak_pages_in_use": 0, "decode_bucket_tokens": 0,
@@ -1075,13 +1113,14 @@ class Engine:
         """
         queue = list(requests)
         self._t_gen0 = time.perf_counter()
+        wall0 = time.time_ns()
         d0, p0 = self.stats["decode_s"], self.stats["prefill_s"]
         # the ambient mesh context activates the model-internal shard hints
         # (sharding.logical / attn_hint) while the jits' explicit in/out
         # NamedShardings pin the step boundaries
         ctx = jax.set_mesh(self.sc.mesh) if self.sc.mesh is not None \
             else contextlib.nullcontext()
-        with ctx:
+        with ctx, pl.GcSpans():
             if self.scheduler == "static":
                 for w0 in range(0, len(queue), self.batch):
                     self._run_wave(queue[w0:w0 + self.batch])
@@ -1089,7 +1128,7 @@ class Engine:
                 self._run_continuous(queue)
                 # honest attribution: whatever this call's wall time was not
                 # spent dispatching/waiting on the device is host overhead
-                wall = time.perf_counter() - self._t_gen0
+                wall = (time.time_ns() - wall0) * 1e-9
                 self.stats["host_s"] += wall \
                     - (self.stats["decode_s"] - d0) \
                     - (self.stats["prefill_s"] - p0)
@@ -1193,8 +1232,9 @@ class Engine:
         if not self._staged:
             return cache
         staged, self._staged = self._staged, []
-        t0 = time.perf_counter()
         bucket = max(b for (_, _, _, b) in staged)
+        self._admitted[0] += len(staged)
+        self._admitted[1] = max(self._admitted[1], bucket)
         rows = self.ladder.pad_rows(len(staged), self.batch)
         tokens = np.zeros((rows, bucket), np.int32)
         lengths = np.full(rows, bucket, np.int32)
@@ -1207,35 +1247,38 @@ class Engine:
         if self.sc.temperature > 0.0:
             self.rng, sub = jax.random.split(self.rng)
             args.append(sub)
-        first, rows_cache = self._admit_step(*args)
-        if self.paged:
-            # splice through the block table: each row's full prompt blocks
-            # land in its slot's reserved pages; bucket padding blocks (and
-            # whole padding rows) carry out-of-range ids the device scatter
-            # drops. Nothing max_seq-sized is written.
-            page_ids = np.full((rows, bucket // kvc.BLOCK), self._n_pages,
-                               np.int32)
-            table = np.zeros((rows, self.sc.max_seq // kvc.BLOCK), np.int32)
-            for j, (r, slot, plen, _) in enumerate(staged):
-                pb = plen // kvc.BLOCK
-                pages = self._slot_pages[slot]
-                # shared prefix blocks are NOT rewritten (their ids stay
-                # out-of-range so the scatter drops them) — that is the
-                # copy-on-write contract; the table still maps them so the
-                # attend reads the shared pages
-                sh_n = self._slot_shared[slot]
-                page_ids[j, sh_n:pb] = pages[sh_n:pb]
-                table[j, :pb] = pages[:pb]
-            cache = self._write(cache, rows_cache, jnp.asarray(slot_ids),
-                                jnp.asarray(page_ids), jnp.asarray(table))
-            if self._prefix is not None \
-                    and any(self._slot_shared[s] for (_, s, _, _) in staged):
-                cache = self._verify_shared(cache, staged, rows_cache,
-                                            rows, bucket, slot_ids)
-        else:
-            cache = self._write(cache, rows_cache, jnp.asarray(slot_ids))
-        firsts = np.asarray(first)
-        self.stats["prefill_s"] += time.perf_counter() - t0
+        with pl.Span("serve.prefill", rows=len(staged), bucket=bucket):
+            first, rows_cache = self._admit_step(*args)
+            if self.paged:
+                # splice through the block table: each row's full prompt
+                # blocks land in its slot's reserved pages; bucket padding
+                # blocks (and whole padding rows) carry out-of-range ids the
+                # device scatter drops. Nothing max_seq-sized is written.
+                page_ids = np.full((rows, bucket // kvc.BLOCK), self._n_pages,
+                                   np.int32)
+                table = np.zeros((rows, self.sc.max_seq // kvc.BLOCK),
+                                 np.int32)
+                for j, (r, slot, plen, _) in enumerate(staged):
+                    pb = plen // kvc.BLOCK
+                    pages = self._slot_pages[slot]
+                    # shared prefix blocks are NOT rewritten (their ids stay
+                    # out-of-range so the scatter drops them) — that is the
+                    # copy-on-write contract; the table still maps them so
+                    # the attend reads the shared pages
+                    sh_n = self._slot_shared[slot]
+                    page_ids[j, sh_n:pb] = pages[sh_n:pb]
+                    table[j, :pb] = pages[:pb]
+                cache = self._write(cache, rows_cache, jnp.asarray(slot_ids),
+                                    jnp.asarray(page_ids), jnp.asarray(table))
+                if self._prefix is not None and any(
+                        self._slot_shared[s] for (_, s, _, _) in staged):
+                    cache = self._verify_shared(cache, staged, rows_cache,
+                                                rows, bucket, slot_ids)
+            else:
+                cache = self._write(cache, rows_cache, jnp.asarray(slot_ids))
+        with pl.Span("serve.readback", self.stats, ("wait_s",),
+                     step=self.stats["steps"]):
+            firsts = np.asarray(first)
         t_emit = time.perf_counter()
         fix_i, fix_t, fix_p = [], [], []
         for j, (r, slot, plen, _) in enumerate(staged):
@@ -1363,6 +1406,16 @@ class Engine:
         self._devpos[np.asarray(idx, np.int64)] = pos_vals
 
     def _admit_free_slots(self, queue, cache):
+        """One `serve.admit` span (rows admitted, widest bucket) around
+        `_fill_free_slots`; its duration is added to stats["prefill_s"]."""
+        self._admitted = [0, 0]
+        with pl.Span("serve.admit", self.stats, ("prefill_s",)) as span:
+            cache = self._fill_free_slots(queue, cache)
+            span.set_metadata(rows=self._admitted[0],
+                              bucket=self._admitted[1])
+        return cache
+
+    def _fill_free_slots(self, queue, cache):
         """Fill free slots from the queue (paged pools additionally gate on
         free pages, FCFS) and flush the staged group through one packed
         prefill (`packed_admission=False` caps the group at 1 — the serial
@@ -1381,7 +1434,9 @@ class Engine:
             self._worker.flush()
         resumed: tuple | list = ()
         if self._tier is not None:
-            cache, resumed = self._resume_parked(cache)
+            if self._park_order:
+                with pl.Span("serve.resume", parked=len(self._park_order)):
+                    cache, resumed = self._resume_parked(cache)
             if want and len(self._free_pages) < self._wm_low:
                 cache = self._evict_until(self._wm_high, cache,
                                           protect=resumed)
@@ -1429,8 +1484,8 @@ class Engine:
         every slot (no speculative step is outstanding) and the free list
         reflects every retirement — the quiescent state parking needs."""
         while self._pending:
-            fut, plive = self._pending.popleft()
-            cache = self._process(fut, plive, cache)
+            fut, plive, step = self._pending.popleft()
+            cache = self._process(fut, plive, step, cache)
         self._worker.flush()
         return cache
 
@@ -1576,72 +1631,87 @@ class Engine:
             cache = self._drain_pending(cache)
             if self._slots[v] is None:
                 continue  # retired while draining — its pages came back free
-            ok, cache = self._park_slot(v, cache)
+            with pl.Span("serve.park", slot=v):
+                ok, cache = self._park_slot(v, cache)
             if not ok:
                 break  # host pool exhausted — stop evicting
         return cache
 
     def _dispatch(self, cache, live):
-        """Issue one fused decode step; token/pos stay on device."""
-        t0 = time.perf_counter()
-        args = [self.params, self._tok_dev, cache, self._pos_dev]
-        decode = self._decode
-        if self.paged:
-            # decode-bucket ladder: the attend only reads table entries
-            # below a row's flushed watermark (pos//8*8 — the page flushed
-            # THIS step is still the raw tail), so the deepest live slot's
-            # watermark picks the smallest warmed bucket that covers every
-            # row. Retired slots' device positions reset to 0 before the
-            # next dispatch, so they never hold the bucket high.
-            need = max(((int(self._devpos[i]) // kvc.BLOCK) * kvc.BLOCK
-                        for i in live), default=0)
-            bucket = self.decode_ladder.bucket_for(need)
-            decode = self._decode_fns[bucket]
-            self.stats["decode_bucket_tokens"] += bucket
-            # hand each flushing row its reserved page; every other row gets
-            # an out-of-range id the device scatter drops. `_devpos` mirrors
-            # the DEVICE position (which advances on speculative steps the
-            # host hasn't processed yet); the length guard drops the flush
-            # of a row whose retirement is already in flight.
-            fp = np.full(self.batch, self._n_pages, np.int32)
-            for i in live:
-                p = int(self._devpos[i])
-                blk = p // kvc.BLOCK
-                if p % kvc.BLOCK == kvc.BLOCK - 1 \
-                        and blk < len(self._slot_pages[i]):
-                    page = self._slot_pages[i][blk]
-                    if self._prefix is not None:
-                        # copy-on-write guarantee: decode only ever flushes
-                        # PAST the shared prefix, into a page this slot
-                        # owns exclusively — a write to a shared page is
-                        # structurally impossible, asserted here
-                        assert blk >= self._slot_shared[i] \
-                            and self._page_refs[page] == 1, \
-                            (i, blk, page, int(self._page_refs[page]))
-                    fp[i] = page
-            args.append(jnp.asarray(fp))
-        if self.sc.temperature > 0.0:
-            self.rng, sub = jax.random.split(self.rng)
-            args.append(sub)
-        tok, pos1, cache = decode(*args)
-        self._tok_dev, self._pos_dev = tok, pos1
-        self._devpos += 1
-        self.stats["steps"] += 1
-        self.stats["slot_steps_total"] += self.batch
-        self.stats["slot_steps_live"] += len(live)
-        self.stats["decode_s"] += time.perf_counter() - t0
-        return cache, tok
+        """Dispatch one fused decode step (a `serve.dispatch` span, added to
+        stats["decode_s"]); token/pos stay on device. Returns the cache,
+        the step's token future and the step's number."""
+        step = self.stats["steps"] + 1
+        with pl.Span("serve.dispatch", self.stats, ("decode_s",),
+                     step=step, live=len(live)) as span:
+            args = [self.params, self._tok_dev, cache, self._pos_dev]
+            decode = self._decode
+            if self.paged:
+                # decode-bucket ladder: the attend only reads table entries
+                # below a row's flushed watermark (pos//8*8 — the page
+                # flushed THIS step is still the raw tail), so the deepest
+                # live slot's watermark picks the smallest warmed bucket
+                # that covers every row. Retired slots' device positions
+                # reset to 0 before the next dispatch, so they never hold
+                # the bucket high.
+                need = max(((int(self._devpos[i]) // kvc.BLOCK) * kvc.BLOCK
+                            for i in live), default=0)
+                bucket = self.decode_ladder.bucket_for(need)
+                decode = self._decode_fns[bucket]
+                self.stats["decode_bucket_tokens"] += bucket
+                span.set_metadata(bucket=bucket)
+                # hand each flushing row its reserved page; every other row
+                # gets an out-of-range id the device scatter drops.
+                # `_devpos` mirrors the DEVICE position (which advances on
+                # speculative steps the host hasn't processed yet); the
+                # length guard drops the flush of a row whose retirement is
+                # already in flight.
+                fp = np.full(self.batch, self._n_pages, np.int32)
+                for i in live:
+                    p = int(self._devpos[i])
+                    blk = p // kvc.BLOCK
+                    if p % kvc.BLOCK == kvc.BLOCK - 1 \
+                            and blk < len(self._slot_pages[i]):
+                        page = self._slot_pages[i][blk]
+                        if self._prefix is not None:
+                            # copy-on-write guarantee: decode only ever
+                            # flushes PAST the shared prefix, into a page
+                            # this slot owns exclusively — a write to a
+                            # shared page is structurally impossible,
+                            # asserted here
+                            assert blk >= self._slot_shared[i] \
+                                and self._page_refs[page] == 1, \
+                                (i, blk, page, int(self._page_refs[page]))
+                        fp[i] = page
+                args.append(jnp.asarray(fp))
+            if self.sc.temperature > 0.0:
+                self.rng, sub = jax.random.split(self.rng)
+                args.append(sub)
+            tok, pos1, cache = decode(*args)
+            self._tok_dev, self._pos_dev = tok, pos1
+            self._devpos += 1
+            self.stats["steps"] += 1
+            self.stats["slot_steps_total"] += self.batch
+            self.stats["slot_steps_live"] += len(live)
+        return cache, tok, step
 
-    def _process(self, fut, plive, cache):
-        """Read one completed step's tokens and apply its bookkeeping.
+    def _process(self, fut, plive, step, cache):
+        """Read decode step `step`'s tokens (a `serve.readback` span, added
+        to stats["wait_s"] and stats["decode_s"]) and apply its bookkeeping
+        (a `serve.process` span).
 
         `plive` is the (slot, request) snapshot at dispatch time; a slot
         retired (or re-admitted) while the step was in flight is skipped —
         the speculative step only ever touched that slot's own planes, all
         overwritten at the next admission."""
-        t0 = time.perf_counter()
-        toks = np.asarray(fut)  # the only device->host sync of the loop
-        self.stats["decode_s"] += time.perf_counter() - t0
+        with pl.Span("serve.readback", self.stats, ("wait_s", "decode_s"),
+                     step=step):
+            toks = np.asarray(fut)  # the only device->host sync of the loop
+        with pl.Span("serve.process", step=step):
+            return self._apply_step(toks, plive, cache)
+
+    def _apply_step(self, toks, plive, cache):
+        """Bookkeeping of one read decode step: append, retire, re-fix."""
         t_emit = time.perf_counter()
         emitted, retired, fix_i = [], [], []
         for i, r in plive:
@@ -1730,11 +1800,12 @@ class Engine:
                 if live:
                     self.stats["peak_live_slots"] = max(
                         self.stats["peak_live_slots"], len(live))
-                    cache, fut = self._dispatch(cache, [i for i, _ in live])
-                    pending.append((fut, live))
+                    cache, fut, step = self._dispatch(
+                        cache, [i for i, _ in live])
+                    pending.append((fut, live, step))
                 if len(pending) > depth or (pending and not live):
-                    fut, plive = pending.popleft()
-                    cache = self._process(fut, plive, cache)
+                    fut, plive, step = pending.popleft()
+                    cache = self._process(fut, plive, step, cache)
             # queue drained: record the DATA-DEPENDENT pool footprint next to
             # the analytic one (kv_pool_stats reports both) — variable-length
             # codec families (bitplane) are the reason the two differ.  Raw
@@ -1763,9 +1834,9 @@ class Engine:
         for i, r in enumerate(slots):
             prompts[i, plen - len(r.prompt):] = r.prompt  # right-align
 
-        t0 = time.perf_counter()
-        logits, cache = self._prefill(self.params, jnp.asarray(prompts))
-        self.stats["prefill_s"] += time.perf_counter() - t0
+        with pl.Span("serve.admit", self.stats, ("prefill_s",),
+                     rows=len(wave), bucket=plen):
+            logits, cache = self._prefill(self.params, jnp.asarray(prompts))
 
         # explicit ordering: sample from prefill -> append/check -> only then
         # decode. If every request finishes on its first token, no decode
@@ -1773,9 +1844,12 @@ class Engine:
         token = self._sample(logits[:, -1])
         max_new = max(r.max_new for r in wave)
         done = np.zeros(self.batch, bool)
-        t0 = time.perf_counter()
         for step in range(max_new):
-            tok_np = np.asarray(token)
+            # the first read waits on the prefill, each later one on the
+            # decode step dispatched last
+            with pl.Span("serve.readback", self.stats, ("wait_s", "decode_s"),
+                         step=self.stats["steps"]):
+                tok_np = np.asarray(token)
             for i, r in enumerate(slots):
                 if r.uid >= 0 and not r.done:
                     tok = int(tok_np[i])
@@ -1794,10 +1868,12 @@ class Engine:
                     if r.uid >= 0:
                         r.done = True
                 break
-            logits_step, cache = self._decode(self.params, token, cache,
-                                              jnp.int32(plen + step))
-            token = self._sample(logits_step)
+            live = int((~done).sum())
+            with pl.Span("serve.dispatch", self.stats, ("decode_s",),
+                         step=self.stats["steps"] + 1, live=live):
+                logits_step, cache = self._decode(self.params, token, cache,
+                                                  jnp.int32(plen + step))
+                token = self._sample(logits_step)
             self.stats["steps"] += 1
             self.stats["slot_steps_total"] += self.batch
-            self.stats["slot_steps_live"] += int((~done).sum())
-        self.stats["decode_s"] += time.perf_counter() - t0
+            self.stats["slot_steps_live"] += live

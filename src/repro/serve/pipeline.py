@@ -1,6 +1,6 @@
 """Host-side serving pipeline: AOT prefill/decode buckets + async bookkeeping.
 
-Four pieces, all host machinery (nothing here traces into a jit):
+Five pieces, all host machinery (nothing here traces into a jit):
 
   * `PrefillLadder` — the fixed set of prompt-length buckets the engine
     compiles AHEAD of traffic.  Admission rounds every prompt up to the
@@ -34,13 +34,20 @@ Four pieces, all host machinery (nothing here traces into a jit):
     effect, so `counts` advances exactly when XLA (re)compiles.  The
     zero-compile-under-traffic regression test snapshots the counts after
     warmup and asserts serving moves none of them.
+
+  * `Span` / `GcSpans` — host spans: `jax.profiler.TraceAnnotation`s with
+    integer metadata that land in the same profile as the device ops, and
+    the engine's time counters, each the summed duration of one span.
 """
 from __future__ import annotations
 
+import gc
 import queue
 import threading
 import time
 from dataclasses import dataclass
+
+from jax.profiler import TraceAnnotation
 
 BLOCK = 8  # the DCT seq-block; ladder buckets are multiples of it
 
@@ -198,6 +205,59 @@ def counting(name: str, counts: TraceCounts, fn):
 
 
 # ---------------------------------------------------------------------------
+# Host spans
+# ---------------------------------------------------------------------------
+
+class Span(TraceAnnotation):
+    """A host span: a `TraceAnnotation` (integer metadata only; the name
+    and metadata are encoded only while a profiler is recording) whose
+    duration, on the profiler's clock (`time.time_ns`), is added to
+    `stats[k]` for each k in `keys` when it closes."""
+
+    def __init__(self, name: str, stats: dict | None = None,
+                 keys: tuple = (), **meta):
+        super().__init__(name, **meta)
+        self._stats, self._keys = stats, keys
+
+    def __enter__(self):
+        super().__enter__()
+        self._t0 = time.time_ns()
+        return self
+
+    def __exit__(self, *exc):
+        dt = (time.time_ns() - self._t0) * 1e-9
+        for k in self._keys:
+            self._stats[k] += dt
+        return super().__exit__(*exc)
+
+
+class GcSpans:
+    """While entered, each collection of Python's garbage collector is a
+    `python.gc` span (metadata: the generation collected), so a collector
+    pause has a name in the profile."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, phase, info):
+        if phase == "start" and self._open is None:
+            self._open = TraceAnnotation("python.gc",
+                                         generation=info["generation"])
+            self._open.__enter__()
+        elif phase == "stop" and self._open is not None:
+            span, self._open = self._open, None
+            span.__exit__(None, None, None)
+
+    def __enter__(self):
+        gc.callbacks.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self)
+        self("stop", None)
+
+
+# ---------------------------------------------------------------------------
 # Background bookkeeping worker
 # ---------------------------------------------------------------------------
 
@@ -209,7 +269,9 @@ class BackgroundWorker:
     per-request token order and free-list state are deterministic.  Errors
     are captured and re-raised on the serve thread at the next `flush()` /
     `close()` — a bookkeeping bug must fail the request loop, not vanish
-    in a thread."""
+    in a thread.  Each closure runs inside a `serve.bookkeeping` span, and
+    each `flush()` the serve thread blocks on is a `serve.worker_flush`
+    span."""
 
     def __init__(self, name: str = "serve-bookkeeping"):
         self._q: queue.Queue = queue.Queue()
@@ -225,7 +287,8 @@ class BackgroundWorker:
                 if item is None:
                     return
                 if self._err is None:
-                    item()
+                    with TraceAnnotation("serve.bookkeeping"):
+                        item()
             except BaseException as e:  # surfaced at flush()/close()
                 self._err = e
             finally:
@@ -238,7 +301,8 @@ class BackgroundWorker:
 
     def flush(self) -> None:
         """Block until every submitted closure has run; re-raise errors."""
-        self._q.join()
+        with TraceAnnotation("serve.worker_flush"):
+            self._q.join()
         if self._err is not None:
             self._reraise()
 

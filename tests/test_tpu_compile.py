@@ -100,6 +100,32 @@ def test_decompress_plane_compiles(one_chip, plane, keep):
     _assert_kernel(compiled)
 
 
+
+def test_kernels_carry_their_names(one_chip):
+    """Each main-path kernel is an instruction named after it, which is how
+    a profile's op events name it."""
+    import re
+
+    hd, hkv, n_rep = WIDTHS["qwen2_0_5b"]
+    pool = (PAGES, hkv, hd // 8, 4, 4)
+    attend = _compile(
+        lambda pk, sk, q, pos, bt, t: attend_paged(
+            pk, sk, pk, sk, q, pos, bt, t, t, pages_per_tile=G,
+            interpret=False),
+        one_chip, (pool, jnp.int8), ((PAGES, hkv, hd // 8), jnp.float32),
+        ((B, hkv, n_rep, hd), jnp.bfloat16), ((B,), jnp.int32),
+        ((B, MAX_SEQ // 8), jnp.int32), ((B, 8, hkv, hd), jnp.bfloat16))
+    r, c = _planes()[0]
+    codec = _compile(
+        lambda x: decompress_plane_pallas(
+            *compress_plane_pallas(x, 4, interpret=False), interpret=False),
+        one_chip, ((r, c), jnp.float32))
+    for name, compiled in (("attend_paged", attend),
+                           ("fused_compress", codec),
+                           ("fused_decompress", codec)):
+        assert re.search(rf"%{name}(\.\d+)? = .*tpu_custom_call",
+                         compiled.as_text()), name
+
 def test_qwen2_paged_decode_step_fits_one_v5e(one_chip, monkeypatch):
     """The whole compressed paged decode step of qwen2_0_5b at published
     widths compiles for one v5e and fits its 16 GB with room to spare."""
