@@ -270,17 +270,19 @@ def check_kernel(cache, lengths, seed: int) -> None:
     check(dispatch.resolve_interpret(None) is False,
           "attend_paged: interpret resolved to False")
     seg = cache.segments[0]
-    lc = {k: v[0] for k, v in seg.as_tree().items()}
+    # the stacked page rows (read at layer 0) beside layer 0's tails
+    lc = dict(seg.as_tree(), **{k: seg.planes[k][0] for k in kvc.TAIL_NAMES})
+    paged = dict(keep=seg.keep, layer=0, n_pages=cache.n_pages)
     cfg_h, hd = PUBLISHED["n_heads"], PUBLISHED["head_dim"]
     q = jax.random.normal(jax.random.PRNGKey(seed + 1),
                           (BATCH, 1, cfg_h, hd), jnp.float32)
     table = cache.block_table
     got = jax.jit(lambda q, lc, pos, t: fa_ops.attend_with_tail(
-        q, lc, pos, block_table=t))(q, lc, lengths, table)
+        q, lc, pos, block_table=t, **paged))(q, lc, lengths, table)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(lambda q, lc, pos, t: kvc.attend_compressed(
-            q, lc, pos, seg.keep, block_table=t, backend="reference"))(
-                q, lc, lengths, table)
+            q, lc, pos, paged["keep"], block_table=t, backend="reference",
+            layer=0, n_pages=paged["n_pages"]))(q, lc, lengths, table)
     diff = float(np.max(np.abs(np.asarray(got) - np.asarray(want))))
     check(diff <= KERNEL_ATOL,
           f"compiled attend_paged vs reference attend_compressed on this "

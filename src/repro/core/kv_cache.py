@@ -42,8 +42,10 @@ into the PE stream".
 PAGED POOL (the paper's dynamic feature-map buffer allocation, literally):
 instead of a dense per-slot `(B, S/8, ...)` store provisioned for max_seq,
 `PagedKVCache` keeps a shared page pool whose page unit is ONE 8-token DCT
-block group across all layers — per segment `packed_* (Lseg, P, Hkv, hd/8,
-k, k)` — addressed through a per-slot block table `(B, S/8) -> page id`.
+block group across all layers — per segment the family's planes stored as
+rows of 128 32-bit words (`core.page_rows`: `rows_codes (Lseg, rows, 1,
+128)` int32 and `rows_scale` f32 for dct) — addressed through a per-slot
+block table `(B, S/8) -> page id`.
 Because every layer of a slot flushes the same block index at the same step
 (one position vector drives them all), a single block-table entry covers
 all layers.  Pages are assigned by the HOST (the serve engine owns the free
@@ -64,8 +66,9 @@ import numpy as np
 from repro import codec as codec_lib
 from repro.codec import families as families_lib
 from repro.codec import plan as plan_lib
-from repro.parallel.sharding import (attn_hint, logical as shard_hint,
-                                     table_slice_hint)
+from repro.core import page_rows
+from repro.parallel.sharding import (ambient_mesh, attn_hint,
+                                     logical as shard_hint, table_slice_hint)
 
 BLOCK = 8
 
@@ -192,10 +195,23 @@ class KVSegment:
         return families_lib.get_family(self.codec)
 
     @property
+    def paged(self) -> bool:
+        """Whether the block planes are page-pool rows (`core.page_rows`)."""
+        return any(page_rows.is_row_key(n) for n in self.planes)
+
+    @property
     def page_keys(self) -> tuple[str, ...]:
-        """Names of the block planes that live in the paged pool (every
-        plane the family declares; tails stay per slot)."""
+        """Names of the block planes a page carries (every plane the family
+        declares, ``{name}_k`` / ``{name}_v``; tails stay per slot) — the
+        keys of the page update trees, whatever the storage."""
+        if self.paged:
+            return page_rows.segment_layout(self).plane_names
         return tuple(n for n in self._names() if n not in TAIL_NAMES)
+
+    @property
+    def row_keys(self) -> tuple[str, ...]:
+        """Names of a paged segment's stored row arrays."""
+        return tuple(n for n in self._names() if page_rows.is_row_key(n))
 
     def as_tree(self) -> dict[str, jax.Array]:
         """The {packed_k, ..., tail_v} dict layer-sliceable consumers scan."""
@@ -208,10 +224,11 @@ class KVSegment:
 
     def nbytes(self) -> float:
         """Device bytes held by this segment's planes — the literal sum of
-        the array buffers.  For the dct family this equals the analytic
-        `codec.api.tile_bytes` charge exactly (int8 corner + 4-byte f32
-        scale header, nothing else), so the pool report cannot drift from
-        the codec accounting; tests/test_plan.py pins that identity.
+        the array buffers.  For a dense dct segment this equals the
+        analytic `codec.api.tile_bytes` charge exactly (int8 corner + 4-byte
+        f32 scale header, nothing else), so the pool report cannot drift
+        from the codec accounting; tests/test_plan.py pins that identity.
+        A paged segment's rows add their padding, if any (`page_rows`).
         """
         return float(sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
                          for a in self.planes.values()))
@@ -359,11 +376,27 @@ class PagedKVCache:
 
     `segments` are ordinary `KVSegment`s whose storage planes are PAGE
     pools instead of per-slot stores (tails stay per slot — an 8-token raw
-    ring is not worth paging):
+    ring is not worth paging).  A page's planes are stored as rows of 128
+    32-bit words (`core.page_rows`), for dct:
 
-      packed_k/v : (Lseg, P, Hkv, hd/8, k, k) int8
-      scale_k/v  : (Lseg, P, Hkv, hd/8)       f32
-      tail_k/v   : (Lseg, B, 8, Hkv, hd)      raw dtype
+      rows_codes : (Lseg, P * R, 1, 128) int32  a page's int8 corners, K
+                   then V, all KV heads, 4 to a word: R = ceil(2 * Hkv *
+                   hd/8 * k * ceil(k/4) / 128) rows (1 for qwen2-0.5B's
+                   2 x 2 x 8 x 16 B at k=4, 4 for yi-6B's); pages of fewer
+                   than 65 words share a row
+      rows_scale : (Lseg, rows, 1, 128) f32     the f32 scales, K then V,
+                   all heads: 128 // (2 * Hkv * hd/8) pages to a row, or
+                   whole rows per page
+      tail_k/v   : (Lseg, B, 8, Hkv, hd)        raw dtype
+
+    Rows of exactly 128 words keep one byte layout under every tiling the
+    TPU compiler picks, so the decode step carries the stacked pool
+    through its layer scan, scatters a layer's flushed pages at
+    [layer, page rows] and `attend_paged` fetches pages by (layer, page)
+    — with no relayout copy of the pool anywhere.  Page-update trees
+    (admission, tier spill/restore, prefix verification) keep the plane
+    form ``{name}_k/v (Lseg, ..., Hkv) + block_shape``; `page_rows.pack`
+    / `unpack` convert bitwise.
 
     One page = one 8-token block group ACROSS all layers: every layer of a
     slot flushes the same block index at the same step, so page index p in
@@ -372,30 +405,31 @@ class PagedKVCache:
     engine's host-side free list decides which page that is.  Unmapped
     entries hold 0 (a valid page, so gathers never go out of range) and are
     unreachable: attention masks `kv_pos < flushed` before any gather.
+    `n_pages` is static: shared rows do not fix the page count.
     """
 
     segments: tuple[KVSegment, ...]
     block_table: jax.Array  # (B, S/8) int32
+    n_pages: int            # static
 
     def tree_flatten(self):
-        return (self.segments, self.block_table), ()
+        return (self.segments, self.block_table), (self.n_pages,)
 
     def tree_flatten_with_keys(self):
         ga = jax.tree_util.GetAttrKey
         return ((ga("segments"), self.segments),
-                (ga("block_table"), self.block_table)), ()
+                (ga("block_table"), self.block_table)), (self.n_pages,)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(tuple(children[0]), children[1])
+        return cls(tuple(children[0]), children[1], *aux)
+
+    def replace(self, segments, block_table) -> "PagedKVCache":
+        return PagedKVCache(tuple(segments), block_table, self.n_pages)
 
     @property
     def n_layers(self) -> int:
         return self.segments[-1].stop
-
-    @property
-    def n_pages(self) -> int:
-        return self.segments[0].packed_k.shape[1]
 
     @property
     def max_seq(self) -> int:
@@ -416,9 +450,9 @@ class PagedKVCache:
         granule) — each segment charged by its own codec family."""
         total = 0
         for s in self.segments:
-            _, _, hkv, nhd, k, _ = s.packed_k.shape
+            _, _, _, hkv, hd = s.tail_k.shape
             total += (s.stop - s.start) * \
-                block_group_bytes(k, hkv, nhd * BLOCK, codec=s.codec)
+                block_group_bytes(s.keep, hkv, hd, codec=s.codec)
         return total
 
 
@@ -440,13 +474,16 @@ def init_paged_cache(cfg, batch: int, max_seq: int, n_pages: int,
     hkv = cfg.n_kv_heads
     segments = []
     for start, stop, pol in plan.segments(cfg.n_layers):
-        planes = _segment_planes(pol, stop - start, (n_pages,), batch,
-                                 hkv, hd, dtype)
+        layout = page_rows.row_layout(pol.codec, pol.kv_keep, hkv, hd)
+        planes = page_rows.zeros(layout, stop - start, n_pages)
+        for name in TAIL_NAMES:
+            planes[name] = jnp.zeros((stop - start, batch, BLOCK, hkv, hd),
+                                     dtype)
         segments.append(KVSegment(planes, keep=pol.kv_keep, start=start,
                                   stop=stop, backend=pol.backend,
                                   codec=pol.codec))
     table = jnp.zeros((batch, max_seq // BLOCK), jnp.int32)
-    return PagedKVCache(tuple(segments), table)
+    return PagedKVCache(tuple(segments), table, int(n_pages))
 
 
 def measured_cache_bytes(cache) -> float:
@@ -464,13 +501,8 @@ def measured_cache_bytes(cache) -> float:
     their full buffer size.  Host-side accounting — syncs the planes it
     inspects; call from stats paths, not the decode loop.
     """
-    total = 0.0
-    for seg in cache.segments:
-        planes = seg.as_tree()
-        fam = seg.family
-        for name in TAIL_NAMES:
-            a = planes[name]
-            total += int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+    def block_bytes(planes, fam, keep):
+        total = 0.0
         for sfx in ("_k", "_v"):
             if "blen" + sfx in planes:
                 blen = np.asarray(planes["blen" + sfx])
@@ -484,13 +516,60 @@ def measured_cache_bytes(cache) -> float:
                 if "scale" + sfx in planes:
                     live = live | (np.asarray(planes["scale" + sfx]) != 0)
                 total += float(np.count_nonzero(live)) * \
-                    fam.analytic_tile_bytes(seg.keep)
+                    fam.analytic_tile_bytes(keep)
+        return total
+
+    total = 0.0
+    for seg in cache.segments:
+        planes = seg.as_tree()
+        for name in TAIL_NAMES:
+            a = planes[name]
+            total += int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+        if seg.paged:
+            # every page in plane form, one layer at a time: the unpacked
+            # copy of a whole pool need not fit beside it on the device
+            layout = page_rows.segment_layout(seg)
+            ids = jnp.arange(cache.n_pages)
+            views = (page_rows.gather_planes(layout, planes, ids,
+                                             cache.n_pages, layer)
+                     for layer in range(seg.stop - seg.start))
+        else:
+            views = (planes,)
+        total += sum(block_bytes(v, seg.family, seg.keep) for v in views)
     return total
 
 
 # ---------------------------------------------------------------------------
 # Per-layer decode update (operates on the [B, ...] slices for one layer)
 # ---------------------------------------------------------------------------
+
+def _tail_write(layer_cache, k_new, v_new, rows, slot):
+    # per-row scatters: the row index IS the batch index, so under a
+    # slot-sharded pool (data axes on B) every write lands on the shard that
+    # owns the slot — constrain the results so GSPMD keeps it that way
+    # instead of round-tripping the tail ring through a gather.
+    tails = []
+    for name, new in (("tail_k", k_new), ("tail_v", v_new)):
+        t = layer_cache[name].at[rows, slot].set(
+            new[:, 0].astype(layer_cache[name].dtype))
+        tails.append(shard_hint(t, "batch", None, "model", None))
+    return tails
+
+
+def _flush_planes(tk, tv, keep, backend, fam) -> dict[str, jax.Array]:
+    """Compress each row's full tail ring into one block per row, in the
+    family's plane form ``{name}_k/v (B, Hkv) + block_shape``."""
+    # (B, 8, Hkv, hd) -> (B, Hkv, 8, hd) planes -> one block per row
+    qk, sck = compress_kv_blocks(jnp.swapaxes(tk, 1, 2), keep, backend)
+    qv, scv = compress_kv_blocks(jnp.swapaxes(tv, 1, 2), keep, backend)
+    # qk: (B, Hkv, 1, hd/8, k, k) -> (B, Hkv, hd/8, k, k); the family lays
+    # the quantized blocks out into its declared planes
+    upd = {}
+    for sfx, q, sc in (("_k", qk, sck), ("_v", qv, scv)):
+        for name, plane in fam.pack(q[:, :, 0], sc[:, :, 0], keep).items():
+            upd[name + sfx] = plane
+    return upd
+
 
 def update_layer(
     layer_cache: dict[str, jax.Array],
@@ -500,10 +579,10 @@ def update_layer(
     keep: int,
     backend: str | None = None,
     *,
-    flush_page: jax.Array | None = None,  # (B,) page ids (paged pool only)
     codec: str = "dct",
 ) -> dict[str, jax.Array]:
-    """Write each row's new token into its own tail slot; flush per row.
+    """Write each row's new token into its own tail slot; flush per row
+    (the dense store; `update_layer_paged` is the page pool's).
 
     layer_cache holds the codec family's block planes in cache layout —
     ``{name}_k/v (B, S/8, Hkv) + block_shape`` (dct: packed_k/scale_k/
@@ -516,61 +595,23 @@ def update_layer(
     A single global cond skips the codec entirely on steps where NO row
     flushes (7 of 8 steps in lock-step serving) — the per-row decision
     stays a masked scatter either way.
-
-    PAGED pool: pass `flush_page` and pool-shaped block planes
-    ((P, Hkv) + block_shape).  The flush then scatters row b's block into
-    page `flush_page[b]` instead of (b, pos//8); the engine hands out page
-    ids (its free list is the allocator) and sets out-of-range ids (>= P)
-    for rows that must not flush, which the drop-mode scatter discards.
-    The caller owns the block-table update — this function never sees the
-    table.
     """
     fam = families_lib.get_family(codec)
     b = k_new.shape[0]
     pos = as_pos_vec(pos, b)
     rows = jnp.arange(b)
     slot = jnp.mod(pos, BLOCK)
-    # per-row scatters: the row index IS the batch index, so under a
-    # slot-sharded pool (data axes on B) every write lands on the shard that
-    # owns the slot — constrain the results so GSPMD keeps it that way
-    # instead of round-tripping the tail ring through a gather.
-    tail_k = layer_cache["tail_k"].at[rows, slot].set(
-        k_new[:, 0].astype(layer_cache["tail_k"].dtype)
-    )
-    tail_v = layer_cache["tail_v"].at[rows, slot].set(
-        v_new[:, 0].astype(layer_cache["tail_v"].dtype)
-    )
-    tail_k = shard_hint(tail_k, "batch", None, "model", None)
-    tail_v = shard_hint(tail_v, "batch", None, "model", None)
+    tail_k, tail_v = _tail_write(layer_cache, k_new, v_new, rows, slot)
 
-    paged = flush_page is not None
     block_names = tuple(sorted(n for n in layer_cache if n not in TAIL_NAMES))
     blocks = {n: layer_cache[n] for n in block_names}
-    ns = layer_cache["packed_k"].shape[1]  # dense: S/8 blocks; paged: Hkv
+    ns = layer_cache["packed_k"].shape[1]  # S/8 blocks
 
     flush_row = slot == BLOCK - 1
 
     def flush(args):
         blocks, tk, tv = args
-        # (B, 8, Hkv, hd) -> (B, Hkv, 8, hd) planes -> one block per row
-        qk, sck = compress_kv_blocks(jnp.swapaxes(tk, 1, 2), keep, backend)
-        qv, scv = compress_kv_blocks(jnp.swapaxes(tv, 1, 2), keep, backend)
-        # qk: (B, Hkv, 1, hd/8, k, k) -> cache layout (B, Hkv, hd/8, k, k);
-        # the family lays the quantized blocks out into its declared planes
-        upd = {}
-        for sfx, q, sc in (("_k", qk, sck), ("_v", qv, scv)):
-            q = jnp.swapaxes(q, 1, 2)[:, 0]
-            sc = jnp.swapaxes(sc, 1, 2)[:, 0]
-            for name, plane in fam.pack(q, sc, keep).items():
-                upd[name + sfx] = plane
-        if paged:
-            # guard against stray ids on non-flushing rows: force them out
-            # of range so the drop-mode scatter discards them
-            page = jnp.where(flush_row, flush_page,
-                             blocks["packed_k"].shape[0])
-            return {n: blocks[n].at[page].set(
-                upd[n].astype(blocks[n].dtype), mode="drop")
-                for n in block_names}
+        upd = _flush_planes(tk, tv, keep, backend, fam)
         blk = jnp.where(flush_row, pos // BLOCK, ns)  # ns => dropped
         return {n: blocks[n].at[rows, blk].set(
             upd[n].astype(blocks[n].dtype), mode="drop")
@@ -582,21 +623,72 @@ def update_layer(
 
     blocks = jax.lax.cond(jnp.any(flush_row), flush, no_flush,
                           (blocks, tail_k, tail_v))
-    if paged:
-        # pool layout per cache_specs: pages ride the data axes (the batch
-        # scatter above crosses banks by design — the page allocator does
-        # not know about devices), heads on `model` when they divide it
-        blocks = {n: shard_hint(a, "batch", "model", *[None] * (a.ndim - 2))
-                  for n, a in blocks.items()}
-    else:
-        # block-plane layout must MATCH cache_specs: heads on `model` when
-        # they divide it, else the S/8 block axis (attn_hint implements that
-        # fallback) — a plain heads-only hint would conflict with the pool
-        # specs for non-dividing head counts and force a full-store reshard
-        # per step
-        blocks = {n: attn_hint(a, s_axis=1, h_axis=2)
-                  for n, a in blocks.items()}
+    # block-plane layout must MATCH cache_specs: heads on `model` when
+    # they divide it, else the S/8 block axis (attn_hint implements that
+    # fallback) — a plain heads-only hint would conflict with the pool
+    # specs for non-dividing head counts and force a full-store reshard
+    # per step
+    blocks = {n: attn_hint(a, s_axis=1, h_axis=2) for n, a in blocks.items()}
     return dict(blocks, tail_k=tail_k, tail_v=tail_v)
+
+
+def update_layer_paged(
+    pool: dict[str, jax.Array],   # a segment's stacked rows (Lseg, rows, 1, 128)
+    layer: jax.Array,             # () int32 layer index within the segment
+    tails: dict[str, jax.Array],  # this layer's tail_k/tail_v (B, 8, Hkv, hd)
+    k_new: jax.Array,             # (B, 1, Hkv, hd)
+    v_new: jax.Array,
+    pos: jax.Array,               # (B,) per-slot positions
+    flush_page: jax.Array,        # (B,) page ids; >= n_pages => no flush
+    keep: int,
+    backend: str | None = None,
+    *,
+    n_pages: int,
+    codec: str = "dct",
+) -> tuple[dict[str, jax.Array], dict[str, jax.Array]]:
+    """The paged pool's per-layer update: tail write, then flush IN PLACE.
+
+    Every row writes its token into its own tail slot.  Rows whose tail
+    just filled (pos % 8 == 7) flush their 8 tokens into page
+    `flush_page[b]` of layer `layer` of the stacked row pool.  The codec
+    runs under a cond that skips it on steps where no row flushes (7 of 8
+    in lock-step serving); it yields small per-row page words, never the
+    pool.  The write is unconditional: non-flushing rows carry an
+    out-of-range id and write nothing.  On the pallas backend outside a
+    mesh the `kernels/page_write` kernel writes the pages' rows at
+    [layer, page rows] in place; otherwise one drop-mode XLA scatter does.
+    The engine hands out page ids (its free list is the allocator); the
+    caller owns the block-table update.  Returns the new pool and the new
+    tails.
+    """
+    fam = families_lib.get_family(codec)
+    b = k_new.shape[0]
+    pos = as_pos_vec(pos, b)
+    slot = jnp.mod(pos, BLOCK)
+    tail_k, tail_v = _tail_write(tails, k_new, v_new, jnp.arange(b), slot)
+    layout = page_rows.row_layout(codec, keep, tail_k.shape[-2],
+                                  tail_k.shape[-1])
+    flush_row = slot == BLOCK - 1
+    words = jax.lax.cond(
+        jnp.any(flush_row),
+        lambda: page_rows.pack(layout, _flush_planes(tail_k, tail_v, keep,
+                                                     backend, fam)),
+        lambda: {g.key: jnp.zeros((b, g.words), g.dtype)
+                 for g in layout.groups})
+    page = jnp.where(flush_row, flush_page, n_pages)
+    tails = dict(tail_k=tail_k, tail_v=tail_v)
+    if codec_lib.resolve_backend_name(backend) == "pallas" \
+            and ambient_mesh() is None:
+        return {g.key: page_rows.write_layer_pages(
+            g, pool[g.key], layer, page, words[g.key], n_pages)
+            for g in layout.groups}, tails
+    # pool layout per cache_specs: pages ride the data axes (the batch
+    # scatter crosses banks by design — the page allocator does not know
+    # about devices)
+    return {g.key: shard_hint(
+        page_rows.scatter_pages(g, pool[g.key], page, words[g.key], n_pages,
+                                layer), None, "batch", None, None)
+        for g in layout.groups}, tails
 
 
 # ---------------------------------------------------------------------------
@@ -622,6 +714,8 @@ def attend_compressed(
     backend: str | None = None,
     block_table: jax.Array | None = None,  # (B, S/8) page ids (paged pool)
     codec: str = "dct",
+    layer: jax.Array | int = 0,            # paged: layer of the stacked rows
+    n_pages: int | None = None,            # paged: pages in the pool
 ) -> jax.Array:
     """Online-softmax decode attention where K/V history is decompressed per
     chunk INSIDE the scan — compressed bytes are what stream from HBM.
@@ -632,21 +726,27 @@ def attend_compressed(
 
     The codec family unpacks its declared planes back to quantized blocks
     per chunk (for dct that unpack is the identity, so the op stream is
-    bit-for-bit the pre-family path).  With `block_table`, the block planes
-    are the shared PAGE POOL ((P, Hkv) + block_shape) and each chunk
-    gathers its blocks through the table first.  Chunk boundaries and every
-    float op after the gather are identical to the dense layout, so greedy
-    decode over a paged pool is bitwise the dense result (tests pin this).
+    bit-for-bit the pre-family path).  With `block_table`, `layer_cache`
+    holds the shared PAGE POOL — a segment's stacked row arrays
+    (`core.page_rows`, all layers) beside this layer's tails — and each
+    chunk gathers its pages at (`layer`, page) through the table and
+    unpacks them to the family's planes (an XLA gather and bitcasts).
+    Chunk boundaries and every float op after the gather are identical to
+    the dense layout, so greedy decode over a paged pool is bitwise the
+    dense result (tests pin this).
     """
     fam = families_lib.get_family(codec)
-    bases = tuple(sorted({n[:-2] for n in layer_cache if n not in TAIL_NAMES}))
     b, sq, h, hd = q.shape
     pos = as_pos_vec(pos, b)
-    pk = layer_cache["packed_k"]
+    hkv = layer_cache["tail_k"].shape[-2]
+    k = keep
     if block_table is None:
-        _, nblocks_total, hkv, nhd, k, _ = pk.shape
+        bases = tuple(sorted({n[:-2] for n in layer_cache
+                              if n not in TAIL_NAMES}))
+        nblocks_total = layer_cache["packed_k"].shape[1]
     else:
-        _, hkv, nhd, k, _ = pk.shape
+        layout = page_rows.row_layout(codec, keep, hkv, hd)
+        bases = tuple(sorted({n[:-2] for n in layout.plane_names}))
         nblocks_total = block_table.shape[1]
     n_rep = h // hkv
     max_seq = nblocks_total * BLOCK
@@ -665,16 +765,19 @@ def attend_compressed(
         m, l, acc = carry
         start = c * bpc
         if block_table is None:
-            sl = lambda a: jax.lax.dynamic_slice_in_dim(a, start, bpc, 1)
+            chunk = {n: jax.lax.dynamic_slice_in_dim(layer_cache[n], start,
+                                                     bpc, 1)
+                     for n in layer_cache if n not in TAIL_NAMES}
         else:
             # gather this chunk's pages: (B, bpc) table slice -> pool rows.
             # Unmapped entries point at page 0 — valid, and masked below.
             pages = jax.lax.dynamic_slice_in_dim(block_table, start, bpc, 1)
-            sl = lambda a: a[pages]                       # (B, bpc, Hkv, ...)
+            chunk = page_rows.gather_planes(layout, layer_cache, pages,
+                                            n_pages, layer)
 
         def chunk_planes(sfx):
             # planes per (B, Hkv): (B, nb, Hkv, ...) -> (B, Hkv, nb, ...)
-            return {base: jnp.swapaxes(sl(layer_cache[base + sfx]), 1, 2)
+            return {base: jnp.swapaxes(chunk[base + sfx], 1, 2)
                     for base in bases}
 
         kq, ksc = fam.unpack(chunk_planes("_k"), k)
@@ -754,6 +857,8 @@ def attend_auto(
     block_table: jax.Array | None = None,  # (B, nblocks) page ids (paged)
     pages_per_tile: int = 8,
     codec: str = "dct",
+    layer: jax.Array | int = 0,       # paged: layer of the stacked rows
+    n_pages: int | None = None,       # paged: pages in the pool
 ) -> jax.Array:
     """Backend-dispatched decode attention over the compressed store.
 
@@ -763,8 +868,9 @@ def attend_auto(
     repro.codec.dispatch, same as the block codec itself. Both backends take
     the per-slot position vector, and both gather paged history through
     `block_table` when given one — possibly a `table_view` bucket slice —
-    (the kernel reads the table on the scalar-prefetch path beside `pos`;
-    `pages_per_tile` is the kernel's G-page tile width).
+    (the kernel reads the table on the scalar-prefetch path beside `pos`,
+    and the paged pool's `layer` after them; `pages_per_tile` is the
+    kernel's G-page tile width).
 
     Only the dct family's plane layout matches what the fused kernel reads
     (`CodecFamily.supports_fused_attend`); other families always decode
@@ -777,10 +883,12 @@ def attend_auto(
 
         return fa_ops.attend_with_tail(q, layer_cache, pos, tile_s=kv_block,
                                        block_table=block_table,
-                                       pages_per_tile=pages_per_tile)
+                                       pages_per_tile=pages_per_tile,
+                                       keep=keep, layer=layer,
+                                       n_pages=n_pages)
     return attend_compressed(q, layer_cache, pos, keep, kv_block=kv_block,
                              backend=backend, block_table=block_table,
-                             codec=codec)
+                             codec=codec, layer=layer, n_pages=n_pages)
 
 
 # ---------------------------------------------------------------------------
@@ -853,29 +961,40 @@ def paged_write_slot(cache: PagedKVCache, slot_update, slot: jax.Array,
     """Splice one admitted request into the paged pool.
 
     `slot_update` is the per-segment tuple of dicts a paged prefill returns:
-    packed/scale planes hold the prompt's OWN blocks only
-    ((Lseg, 1, nb, ...) with nb = bucket/8 — never max_seq/8), tails are the
-    (Lseg, 1, 8, Hkv, hd) raw remainder.  `page_ids` (nb,) carries the
-    engine-assigned page per prompt block, padded with out-of-range ids
+    block planes hold the prompt's OWN blocks only in plane form
+    ((Lseg, 1, nb, Hkv, ...) with nb = bucket/8 — never max_seq/8), tails
+    are the (Lseg, 1, 8, Hkv, hd) raw remainder.  `page_ids` (nb,) carries
+    the engine-assigned page per prompt block, padded with out-of-range ids
     (>= P) past the prompt's full blocks so the drop-mode scatter ignores
     the padding blocks; `table_row` (S/8,) is the slot's new block-table
     row (assigned pages then zeros).  Admission therefore writes O(prompt)
-    pool bytes plus one table row — nothing max_seq-sized is zero-filled.
+    pool rows plus one table row — nothing max_seq-sized is zero-filled.
     """
     slot = jnp.asarray(slot, jnp.int32)
     segments = []
     for seg, upd in zip(cache.segments, slot_update):
         planes = seg.as_tree()
-        new = {}
-        for key in seg.page_keys:
-            new[key] = planes[key].at[:, page_ids].set(
-                upd[key][:, 0].astype(planes[key].dtype), mode="drop")
+        new = _write_pages(seg, {k: a[:, 0] for k, a in upd.items()},
+                           page_ids, cache.n_pages)
         for key in TAIL_NAMES:
             new[key] = jax.lax.dynamic_update_slice_in_dim(
                 planes[key], upd[key].astype(planes[key].dtype), slot, axis=1)
         segments.append(seg.replace_arrays(new))
     table = cache.block_table.at[slot].set(table_row)
-    return PagedKVCache(tuple(segments), table)
+    return cache.replace(segments, table)
+
+
+def _write_pages(seg, upd, page_ids, n_pages):
+    """Scatter plane-form pages ``(Lseg,) + page_ids.shape + plane`` into a
+    segment's row arrays; returns the new row arrays."""
+    layout = page_rows.segment_layout(seg)
+    ids = jnp.asarray(page_ids, jnp.int32)
+    flat = {k: upd[k].reshape((upd[k].shape[0], ids.size)
+                              + upd[k].shape[1 + ids.ndim:])
+            for k in layout.plane_names}
+    return page_rows.scatter_planes(
+        layout, {k: seg.planes[k] for k in seg.row_keys}, ids.reshape(-1),
+        flat, n_pages)
 
 
 def paged_write_rows(cache: PagedKVCache, rows_update, slots: jax.Array,
@@ -884,30 +1003,26 @@ def paged_write_rows(cache: PagedKVCache, rows_update, slots: jax.Array,
     the paged pool — the batched `paged_write_slot`.
 
     `rows_update` is the per-segment tuple of dicts a paged prefill returns
-    with R rows: packed/scale planes (Lseg, R, nb, ...), tails
+    with R rows: block planes in plane form (Lseg, R, nb, Hkv, ...), tails
     (Lseg, R, 8, Hkv, hd).  `slots` (R,) assigns row r to pool slot
     slots[r]; `page_ids` (R, nb) carries each row's engine-assigned page per
     prompt block; `table_rows` (R, S/8) the new block-table rows.  Rows the
     admission group padded to a warmed row count carry out-of-range slot
     ids (>= B) and all-out-of-range page ids, so every one of their writes
-    drops — a padding row can land nowhere.
+    drops — a padding row can land nowhere.  Each segment's pages land in
+    its row arrays through one drop-mode scatter per row group.
     """
     slots = jnp.asarray(slots, jnp.int32)
     segments = []
     for seg, upd in zip(cache.segments, rows_update):
         planes = seg.as_tree()
-        new = {}
-        for key in seg.page_keys:
-            # planes[key]: (Lseg, P, ...); page_ids (R, nb) gathers to
-            # (Lseg, R, nb, ...) — exactly upd[key]'s shape
-            new[key] = planes[key].at[:, page_ids].set(
-                upd[key].astype(planes[key].dtype), mode="drop")
+        new = _write_pages(seg, upd, page_ids, cache.n_pages)
         for key in TAIL_NAMES:
             new[key] = planes[key].at[:, slots].set(
                 upd[key].astype(planes[key].dtype), mode="drop")
         segments.append(seg.replace_arrays(new))
     table = cache.block_table.at[slots].set(table_rows, mode="drop")
-    return PagedKVCache(tuple(segments), table)
+    return cache.replace(segments, table)
 
 
 def paged_gather_slot(cache: PagedKVCache, slot: jax.Array,
@@ -916,13 +1031,14 @@ def paged_gather_slot(cache: PagedKVCache, slot: jax.Array,
     the tier path, exact inverse of `paged_write_slot`.
 
     Returns the per-segment tuple of dicts `paged_write_slot` accepts:
-    packed/scale planes (Lseg, 1, nb, ...) gathered at `page_ids` (nb,),
-    tails (Lseg, 1, 8, Hkv, hd) sliced at `slot`. Out-of-range page ids
-    clamp to the last page — callers pad the page vector to a warmed bucket
-    width and ignore the padding entries, mirroring the drop-mode scatter
-    on the write side. The engine's TierManager numpy-ifies the result into
-    host pages; feeding it back through `paged_write_slot` at fresh page
-    ids is a bitwise round trip (int8/f32/raw-tail planes copy exactly).
+    block planes in plane form (Lseg, 1, nb, Hkv, ...) read from the rows
+    at `page_ids` (nb,) and unpacked, tails (Lseg, 1, 8, Hkv, hd) sliced at
+    `slot`. Out-of-range page ids clamp to the pool — callers pad the page
+    vector to a warmed bucket width and ignore the padding entries,
+    mirroring the drop-mode scatter on the write side. The engine's
+    TierManager numpy-ifies the result into host pages; feeding it back
+    through `paged_write_slot` at fresh page ids is a bitwise round trip
+    (the unpack is bitcasts; int8/f32/raw-tail planes copy exactly).
 
     Tier semantics: which of a slot's logical blocks are device- vs
     host-resident is HOST state (the engine's per-slot page lists and
@@ -933,10 +1049,9 @@ def paged_gather_slot(cache: PagedKVCache, slot: jax.Array,
     out = []
     for seg in cache.segments:
         planes = seg.as_tree()
-        ids = jnp.minimum(page_ids, planes["packed_k"].shape[1] - 1)
-        upd = {}
-        for key in seg.page_keys:
-            upd[key] = planes[key][:, ids][:, None]  # (Lseg, 1, nb, ...)
+        got = page_rows.gather_planes(page_rows.segment_layout(seg), planes,
+                                      page_ids, cache.n_pages)
+        upd = {key: a[:, None] for key, a in got.items()}  # (Lseg, 1, nb, ...)
         for key in TAIL_NAMES:
             upd[key] = jax.lax.dynamic_slice_in_dim(planes[key], slot, 1,
                                                     axis=1)
@@ -947,22 +1062,22 @@ def paged_gather_slot(cache: PagedKVCache, slot: jax.Array,
 def paged_rows_match(cache: PagedKVCache, rows_update, page_ids: jax.Array):
     """Bitwise-compare pool pages against admission update rows.
 
-    `rows_update` is the (Lseg, R, nb, ...) tree a packed paged prefill
-    returns; `page_ids` (R, nb) names the candidate page per (row, block).
-    Returns an (R, nb) bool: True iff every packed int8 element AND every
-    f32 scale of the candidate page equals the row's freshly computed
-    block — the copy-on-write sharing verifier (hash-equal prefixes are
-    only shared once this says their pages are bitwise equal). Out-of-range
-    ids clamp; callers mask non-candidate entries host-side.
+    `rows_update` is the plane-form (Lseg, R, nb, ...) tree a packed paged
+    prefill returns; `page_ids` (R, nb) names the candidate page per (row,
+    block).  Returns an (R, nb) bool: True iff every packed int8 element
+    AND every f32 scale of the candidate page, read from the rows and
+    unpacked, equals the row's freshly computed block — the copy-on-write
+    sharing verifier (hash-equal prefixes are only shared once this says
+    their pages are bitwise equal). Out-of-range ids clamp; callers mask
+    non-candidate entries host-side.
     """
     ok = jnp.ones(page_ids.shape, bool)
     for seg, upd in zip(cache.segments, rows_update):
-        planes = seg.as_tree()
-        ids = jnp.minimum(page_ids, planes["packed_k"].shape[1] - 1)
+        got = page_rows.gather_planes(page_rows.segment_layout(seg),
+                                      seg.as_tree(), page_ids, cache.n_pages)
         for key in seg.page_keys:
-            got = planes[key][:, ids]  # (Lseg, R, nb, ...)
-            want = upd[key].astype(planes[key].dtype)
-            eq = got == want
+            want = upd[key].astype(got[key].dtype)
+            eq = got[key] == want                 # (Lseg, R, nb, ...)
             axes = tuple(a for a in range(eq.ndim) if a not in (1, 2))
             ok = ok & jnp.all(eq, axis=axes)
     return ok
@@ -987,4 +1102,4 @@ def paged_reset_slot(cache: PagedKVCache, slot: jax.Array) -> PagedKVCache:
         segments.append(seg.replace_arrays(new))
     table = cache.block_table.at[slot].set(
         jnp.zeros_like(cache.block_table[0]))
-    return PagedKVCache(tuple(segments), table)
+    return cache.replace(segments, table)

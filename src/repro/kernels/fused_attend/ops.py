@@ -12,6 +12,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.codec import dispatch as codec_dispatch
+from repro.core import page_rows
 from repro.core.kv_cache import as_pos_vec
 from repro.kernels.fused_attend.kernel import (
     attend_compressed_plane,
@@ -32,6 +33,9 @@ def attend_with_tail(
     interpret: bool | None = None,
     block_table: jax.Array | None = None,  # (B, nblocks) page ids (paged)
     pages_per_tile: int = 8,
+    keep: int | None = None,               # paged: the segment's keep
+    layer: jax.Array | int = 0,            # paged: layer of the stacked rows
+    n_pages: int | None = None,            # paged: pages in the pool
 ) -> jax.Array:
     """Kernel-backed equivalent of core.kv_cache.attend_compressed.
 
@@ -40,35 +44,44 @@ def attend_with_tail(
     flushed watermark. interpret=None auto-selects via the codec dispatch
     rules: compiled on TPU, interpret elsewhere (CPU CI).
 
-    With `block_table` the cache planes are the shared page pool and the
-    fused paged kernel gathers G pages per grid step through the table
-    (page ids on the scalar-prefetch path) and merges the raw tail in its
-    finalize step — one pallas_call emits the normalized output.  The
-    table may be a decode-ladder bucket slice of the full table (see
-    core.kv_cache.table_view): the kernel grid covers only the slice.
+    With `block_table` the cache holds the shared page pool — the
+    segment's stacked row arrays (`core.page_rows`) beside this layer's
+    tails — and the fused paged kernel fetches G pages per grid step by
+    (`layer`, page id), ids and layer on the scalar-prefetch path, and
+    merges the raw tail in its finalize step — one pallas_call emits the
+    normalized output.  The table may be a decode-ladder bucket slice of
+    the full table (see core.kv_cache.table_view): the kernel grid covers
+    only the slice.
     """
     interpret = codec_dispatch.resolve_interpret(interpret)
     b, _, h, hd = q.shape
-    pk = layer_cache["packed_k"]
-    hkv = pk.shape[1] if block_table is not None else pk.shape[2]
+    hkv = layer_cache["tail_k"].shape[-2]
     n_rep = h // hkv
     pos = as_pos_vec(pos, b)
     qg = q[:, 0].reshape(b, hkv, n_rep, hd)
 
     if block_table is not None:
-        # under a mesh each device attends its own slots against the whole
-        # pool: a slot's pages can sit on any data shard (the allocator is
-        # device-agnostic), so the pool planes enter the kernel replicated
+        layout = page_rows.row_layout("dct", keep, hkv, hd)
+        codes = layer_cache[page_rows.CODES]
+        scales = layer_cache[page_rows.ROW_PREFIX + "scale"]
+        layer = jnp.asarray(layer, jnp.int32)
+        if sh.ambient_mesh() is not None:
+            # under a mesh each device attends its own slots against the
+            # whole pool: a slot's pages can sit on any data shard (the
+            # allocator is device-agnostic), so this layer's rows enter the
+            # kernel replicated
+            codes = jax.lax.dynamic_index_in_dim(codes, layer, 0)
+            scales = jax.lax.dynamic_index_in_dim(scales, layer, 0)
+            layer = jnp.int32(0)
         rows = sh.kernel_rows_axes(b)
         out = sh.kernel_map(
-            functools.partial(attend_paged, pages_per_tile=pages_per_tile,
+            functools.partial(attend_paged, layout=layout, n_pages=n_pages,
+                              pages_per_tile=pages_per_tile,
                               interpret=interpret),
-            in_specs=(P(), P(), P(), P(), P(rows), P(rows), P(rows),
-                      P(rows), P(rows)),
+            in_specs=(P(rows), P(rows), P(), P(), P(), P(rows), P(rows),
+                      P(rows)),
             out_specs=P(rows),
-        )(layer_cache["packed_k"], layer_cache["scale_k"],
-          layer_cache["packed_v"], layer_cache["scale_v"],
-          qg, pos, block_table,
+        )(pos, block_table, layer, codes, scales, qg,
           layer_cache["tail_k"], layer_cache["tail_v"],
           )  # (B, Hkv, n_rep, hd) normalized — tail merged in-kernel
         return attn_hint(out.reshape(b, 1, h, hd).astype(q.dtype))

@@ -330,16 +330,17 @@ def cache_specs(cache_shapes: Any, cfg, mesh: Mesh):
         if name in ("tail_k", "tail_v"):            # (L, B, 8, Hkv, hd)
             h = "model" if head_axis_ok(cfg.n_kv_heads) else None
             return P(None, dp, None, h, None)
+        if name.startswith("rows_"):                # (L, rows, 1, 128) page rows
+            # pages ride the data axes; a row holds every KV head of a
+            # page, so heads cannot ride `model`: replicated over it
+            return P(None, dp, None, None)
         base = name[:-2] if name.endswith(("_k", "_v")) else None
         block_nd = _plane_block_ndims().get(base)
         if block_nd is not None:
             # codec-family block plane (families.plane_block_ndims declares
             # the per-block rank n; dct packed n=3, scale n=1, ...):
-            #   paged pool : (L, P, Hkv)      + block_shape  -> rank 3 + n
             #   dense      : (L, B, S/8, Hkv) + block_shape  -> rank 4 + n
             h = "model" if head_axis_ok(cfg.n_kv_heads) else None
-            if nd == 3 + block_nd:                  # paged pool
-                return P(None, dp, h, *([None] * block_nd))
             assert nd == 4 + block_nd, (name, nd, block_nd)
             return P(None, dp, None if h else ("model" if has_model else None),
                      h, *([None] * block_nd))      # dense
@@ -382,9 +383,10 @@ def kv_pool_specs(cfg, plan, mesh: Mesh, *, batch: int, max_seq: int,
     slots) with kv heads on `model` — the same placement `param_specs` gives
     the attention weights, so decode never reshards between them.
 
-    With `n_pages` the tree is the PAGED pool instead: pages and block
+    With `n_pages` the tree is the PAGED pool instead: page rows and block
     tables shard on the data axes (each device/bank owns a slice of the
-    page pool), heads on `model`, tails per slot as before.
+    page pool), replicated over `model` (a row holds every KV head of its
+    page), tails per slot as before.
     """
     from repro.core import kv_cache as kvc  # lazy: core imports stay one-way
 

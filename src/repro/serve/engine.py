@@ -122,15 +122,21 @@ def decode_step_compressed(
     reserved for row b's flush THIS step (out-of-range id = no flush).  The
     block-table row update happens once here — every layer of a slot
     flushes the same block index, so the table is shared — and each layer's
-    update/attend scatters/gathers through it.  `attend_blocks` (the
-    decode-bucket ladder pick, in table entries) statically slices the
-    table the ATTEND sees to the occupied context; the flush update and
-    the cache's stored table always stay full-width.
+    update/attend scatters/gathers through it.  The layer scan CARRIES each
+    segment's stacked page rows with a layer counter (only the per-slot
+    tails and the weights are sliced per layer): the flush scatters into
+    the carried rows at [layer, page rows] and `attend_paged` reads them
+    by (layer, page), so the pool is updated and read in place — no
+    per-layer slice or copy of it.  `attend_blocks` (the decode-bucket
+    ladder pick, in table entries) statically slices the table the ATTEND
+    sees to the occupied context; the flush update and the cache's stored
+    table always stay full-width.
     """
     assert cfg.attn_type == "gqa", "compressed cache is for GQA families"
     b_sz = token.shape[0]
     pos = kvc.as_pos_vec(pos, b_sz)
     paged = isinstance(cache, kvc.PagedKVCache)
+    n_pages = cache.n_pages if paged else None
     if paged:
         assert flush_page is not None, "paged decode needs the flush_page vector"
         nblocks = cache.block_table.shape[1]
@@ -141,10 +147,10 @@ def decode_step_compressed(
         # retired row) reaches pos%8==7 with the out-of-range id, which
         # must not enter the table the attend kernel DMAs pages through.
         # Non-flushing rows are gated by blk=nblocks here (drop) and by
-        # update_layer's own gate on the pool scatter.
+        # update_layer_paged's own gate on the pool scatter.
         with jax.named_scope("kv_flush"):
             flush_row = (jnp.mod(pos, kvc.BLOCK) == kvc.BLOCK - 1) \
-                & (fp >= 0) & (fp < cache.n_pages)
+                & (fp >= 0) & (fp < n_pages)
             blk = jnp.where(flush_row, pos // kvc.BLOCK, nblocks)
             block_table = cache.block_table.at[rows, blk].set(fp,
                                                               mode="drop")
@@ -162,7 +168,10 @@ def decode_step_compressed(
     runs = _param_runs(cfg, params)
 
     def make_layer_step(keep, backend, codec):
-        def layer_step(h, inp):
+        def layer_step(carry, inp):
+            # carry: hidden state, the segment's stacked page rows (paged;
+            # {} dense) and the layer index within the segment
+            h, pool, li = carry
             p, lc = inp["p"], inp["cache"]
             with jax.named_scope("qkv"):
                 hn = norm(p["ln1"], h)
@@ -174,14 +183,20 @@ def decode_step_compressed(
                 k_new, v_new = L.gqa_project_kv(p["attn"], hn, positions,
                                                 cfg)
             with jax.named_scope("kv_flush"):
-                lc2 = kvc.update_layer(lc, k_new, v_new, pos, keep,
-                                       backend=backend, flush_page=fp,
-                                       codec=codec)
+                if paged:
+                    pool, lc2 = kvc.update_layer_paged(
+                        pool, li, lc, k_new, v_new, pos, fp, keep,
+                        backend=backend, n_pages=n_pages, codec=codec)
+                    view = dict(pool, **lc2)
+                else:
+                    lc2 = view = kvc.update_layer(lc, k_new, v_new, pos, keep,
+                                                  backend=backend, codec=codec)
             with jax.named_scope("attend"):
-                attn = kvc.attend_auto(q, lc2, pos, keep, kv_block=kv_block,
+                attn = kvc.attend_auto(q, view, pos, keep, kv_block=kv_block,
                                        backend=backend, block_table=att_table,
                                        pages_per_tile=pages_per_tile,
-                                       codec=codec)
+                                       codec=codec, layer=li,
+                                       n_pages=n_pages)
                 attn = sh.attn_hint(attn)
                 h = h + L.dense(p["attn"]["wo"],
                                 attn.reshape(b, s, cfg.n_heads * hd))
@@ -191,7 +206,7 @@ def decode_step_compressed(
                                       dropless=True)
                 else:
                     h = h + L.mlp(p["mlp"], norm(p["ln2"], h), cfg)
-            return h, lc2
+            return (h, pool, li + 1), lc2
 
         return layer_step
 
@@ -201,6 +216,11 @@ def decode_step_compressed(
             seg.keep, seg.backend if seg.backend is not None else codec_backend,
             seg.codec)
         seg_tree = seg.as_tree()
+        if paged:   # rows ride the carry; only the tails are per layer
+            pool = {n: seg_tree[n] for n in seg.row_keys}
+            seg_tree = {n: seg_tree[n] for n in kvc.TAIL_NAMES}
+        else:
+            pool = {}
         parts = []
         for stack, ps, pe in runs:
             s0, s1 = max(seg.start, ps), min(seg.stop, pe)
@@ -211,17 +231,18 @@ def decode_step_compressed(
                                   seg_tree)
             # the scope holds the copies XLA places around the loop too
             with jax.named_scope("layer_scan"):
-                x, nc = jax.lax.scan(layer_step, x,
-                                     {"p": pslice, "cache": cslice})
+                (x, pool, _), nc = jax.lax.scan(
+                    layer_step, (x, pool, jnp.int32(s0 - seg.start)),
+                    {"p": pslice, "cache": cslice})
             parts.append(nc)
         new_tree = parts[0] if len(parts) == 1 else \
             jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
-        new_segments.append(seg.replace_arrays(new_tree))
+        new_segments.append(seg.replace_arrays(dict(new_tree, **pool)))
 
     with jax.named_scope("unembed"):
         logits = T.unembed(params, x, cfg)[:, 0]
     if paged:
-        return logits, kvc.PagedKVCache(tuple(new_segments), block_table)
+        return logits, cache.replace(new_segments, block_table)
     return logits, kvc.CompressedKVCache(tuple(new_segments))
 
 
@@ -832,10 +853,15 @@ class Engine:
                 "reset", tc,
                 kvc.paged_reset_slot if self.paged else cache_reset_slot)
             fix_fn = pl.counting("fix", tc, token_fix)
+            # on a TPU the paged pool (argument 2 of the decode step) is
+            # donated, so the step's in-place flush needs no whole-pool copy
+            # at the program boundary (the serve loop threads the cache
+            # linearly); elsewhere a step leaves its input cache readable
+            donate = (2,) if jax.default_backend() == "tpu" else ()
             if sc.mesh is None:
                 self._admit_step = jax.jit(admit_fn)
                 if self.paged:
-                    self._decode_fns = {t: jax.jit(fn)
+                    self._decode_fns = {t: jax.jit(fn, donate_argnums=donate)
                                         for t, fn in bucket_fns.items()}
                     self._decode = self._decode_fns[
                         self.decode_ladder.buckets[-1]]
@@ -872,7 +898,8 @@ class Engine:
                     # only on the bound slice width
                     self._decode_fns = {
                         t: jax.jit(fn, in_shardings=tuple(dec_in),
-                                   out_shardings=dec_out)
+                                   out_shardings=dec_out,
+                                   donate_argnums=donate)
                         for t, fn in bucket_fns.items()}
                     self._decode = self._decode_fns[
                         self.decode_ladder.buckets[-1]]

@@ -220,15 +220,18 @@ class Span(TraceAnnotation):
         self._stats, self._keys = stats, keys
 
     def __enter__(self):
-        super().__enter__()
+        # the clock brackets the annotation's own enter and exit, so the
+        # counted time covers the span the profile records
         self._t0 = time.time_ns()
+        super().__enter__()
         return self
 
     def __exit__(self, *exc):
+        out = super().__exit__(*exc)
         dt = (time.time_ns() - self._t0) * 1e-9
         for k in self._keys:
             self._stats[k] += dt
-        return super().__exit__(*exc)
+        return out
 
 
 class GcSpans:
@@ -379,6 +382,7 @@ def warmup_engine(engine) -> float:
     if engine.paged:
         for fn in engine._decode_fns.values():
             tok, pos1, cache = fn(*step_args)
+            step_args[2] = cache  # the step may consume (donate) its pool
     else:
         tok, pos1, cache = engine._decode(*step_args)
     if engine.paged and engine._tier is not None:

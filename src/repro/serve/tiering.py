@@ -47,22 +47,17 @@ import hashlib
 
 import numpy as np
 
+from repro.core import page_rows
+
 BLOCK = 8  # tokens per page (the DCT seq-block)
 
 # The dct family's page planes — kept as a module constant for callers/tests
 # that reason about the default family. TierManager itself derives each
-# segment's plane set from the segment (codec families differ: bitplane pages
-# also carry bpmask/blen planes), so mixed-codec plans tier correctly.
+# segment's plane set from the segment's row layout (codec families differ:
+# bitplane pages also carry bpmask/blen planes), so mixed-codec plans tier
+# correctly.
 PAGE_KEYS = ("packed_k", "scale_k", "packed_v", "scale_v")
 TAIL_KEYS = ("tail_k", "tail_v")
-
-
-def _segment_page_keys(seg) -> tuple[str, ...]:
-    """Pageable plane names for one cache segment (everything but tails)."""
-    keys = getattr(seg, "page_keys", None)
-    if keys is not None:
-        return tuple(keys)
-    return PAGE_KEYS
 
 
 # ---------------------------------------------------------------------------
@@ -146,12 +141,13 @@ class PrefixIndex:
 class TierManager:
     """Host (off-device) page pool + free list for spilled compressed pages.
 
-    The backing store mirrors the device pool's packed/scale geometry with
-    `host_pages` on the page axis: per segment
+    The backing store holds a page's planes in the family's plane form —
+    the form the spill gather unpacks the device rows into and the restore
+    packs back — with `host_pages` on the page axis: per segment
     ``packed_k/v (Lseg, HP, Hkv, hd/8, k, k) int8`` and
-    ``scale_k/v (Lseg, HP, Hkv, hd/8) f32`` — plain numpy, outside any mesh
-    (the parallel/sharding helpers only ever see the restored update on its
-    way back in). Allocation is id-based like the engine's device free
+    ``scale_k/v (Lseg, HP, Hkv, hd/8) f32`` for dct — plain numpy, outside
+    any mesh (the parallel/sharding helpers only ever see the restored
+    update on its way back in). Allocation is id-based like the engine's device free
     list; content moves in `stage_out` (worker thread) and `read_back`
     (admission path, after a `worker.flush()` barrier, so a parked slot's
     bytes are always complete before they stream back).
@@ -164,16 +160,12 @@ class TierManager:
         self._store: list[dict[str, np.ndarray]] = []
         self._page_keys: list[tuple[str, ...]] = []
         for seg in cache_shapes.segments:
-            keys = _segment_page_keys(seg)
-            plane_map = getattr(seg, "planes", None)
-            planes = {}
-            for key in keys:
-                ref = plane_map[key] if plane_map is not None \
-                    else getattr(seg, key)
-                shape = (ref.shape[0], self.host_pages) + tuple(ref.shape[2:])
-                planes[key] = np.zeros(shape, dtype=np.dtype(ref.dtype))
+            shapes = page_rows.segment_layout(seg).plane_shapes()
+            planes = {key: np.zeros((seg.stop - seg.start, self.host_pages)
+                                    + shape, dtype=np.dtype(dt))
+                      for key, (shape, dt) in shapes.items()}
             self._store.append(planes)
-            self._page_keys.append(keys)
+            self._page_keys.append(tuple(sorted(planes)))
 
     @property
     def free_pages(self) -> int:

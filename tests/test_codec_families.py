@@ -19,6 +19,7 @@ from repro.codec import families as families_lib
 from repro.codec import plan as plan_lib
 from repro.core import encode as encode_lib
 from repro.core import kv_cache as kvc
+from repro.core import page_rows
 from repro.models import api as model_api
 from repro.serve import engine as E
 
@@ -288,9 +289,18 @@ def test_segment_planes_follow_family_declaration():
     assert segs[0].page_keys == ("packed_k", "packed_v", "scale_k", "scale_v")
     assert segs[1].page_keys == ("blen_k", "blen_v", "bpmask_k", "bpmask_v",
                                  "packed_k", "packed_v", "scale_k", "scale_v")
-    # paged plane geometry: (Lseg, P, Hkv) + block_shape
-    assert segs[1].planes["bpmask_k"].shape == (2, 16, 2, 4, 2)
-    assert segs[1].planes["blen_k"].shape == (2, 16, 2, 4)
+    # paged plane geometry: (Lseg, P, Hkv) + block_shape in plane form,
+    # stored as 128-word rows: bitplane's int8 corners and mask bytes share
+    # the code rows (2 x 2 heads x 4 blocks x (4 + 1) words = 80 a page),
+    # its int32 lengths and f32 scales take rows of their own (16 words a
+    # page, 8 pages a row)
+    shapes = page_rows.segment_layout(segs[1]).plane_shapes()
+    assert shapes["bpmask_k"] == ((2, 4, 2), jnp.uint8)
+    assert shapes["blen_k"] == ((2, 4), jnp.int32)
+    assert segs[1].row_keys == ("rows_blen", "rows_codes", "rows_scale")
+    assert segs[1].planes["rows_codes"].shape == (2, 16, 1, 128)
+    assert segs[1].planes["rows_blen"].shape == (2, 2, 1, 128)
+    assert segs[1].planes["rows_scale"].shape == (2, 2, 1, 128)
     # analytic page bytes charge each segment's own family
     bp = families_lib.get_family("bitplane").analytic_tile_bytes(4)
     want = (2 * 2 * 2 * 4 * codec_api.tile_bytes(6)) + (2 * 2 * 2 * 4 * bp)

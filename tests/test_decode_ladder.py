@@ -82,17 +82,25 @@ def test_attend_paged_tile_width_invariance():
     off by table_view change nothing."""
     from repro.kernels.fused_attend import ops as fa_ops
 
+    from repro.core import page_rows
+
     b, hkv, n_rep, hd, keep, n_pages = 2, 2, 2, 16, 4, 13
-    nh, depth = hd // 8, 29
+    nh, depth, layer = hd // 8, 29, 1
     rng = np.random.default_rng(3)
-    cache = {
-        "packed_k": jnp.asarray(rng.integers(-8, 8, (n_pages, hkv, nh, keep, keep), np.int8)),
-        "scale_k": jnp.asarray(rng.uniform(0.5, 2, (n_pages, hkv, nh)).astype(np.float32)),
-        "packed_v": jnp.asarray(rng.integers(-8, 8, (n_pages, hkv, nh, keep, keep), np.int8)),
-        "scale_v": jnp.asarray(rng.uniform(0.5, 2, (n_pages, hkv, nh)).astype(np.float32)),
-        "tail_k": jnp.asarray(rng.standard_normal((b, 8, hkv, hd)).astype(np.float32)),
-        "tail_v": jnp.asarray(rng.standard_normal((b, 8, hkv, hd)).astype(np.float32)),
+    planes = {
+        "packed_k": jnp.asarray(rng.integers(-8, 8, (3, n_pages, hkv, nh, keep, keep), np.int8)),
+        "scale_k": jnp.asarray(rng.uniform(0.5, 2, (3, n_pages, hkv, nh)).astype(np.float32)),
+        "packed_v": jnp.asarray(rng.integers(-8, 8, (3, n_pages, hkv, nh, keep, keep), np.int8)),
+        "scale_v": jnp.asarray(rng.uniform(0.5, 2, (3, n_pages, hkv, nh)).astype(np.float32)),
     }
+    # a 3-layer pool in row storage; the attends read layer 1 of it.  At
+    # this width 4 pages share a code row (32 words a page)
+    layout = page_rows.row_layout("dct", keep, hkv, hd)
+    assert layout.group("rows_codes").pages_per_row == 4
+    cache = page_rows.scatter_planes(layout, page_rows.zeros(layout, 3, n_pages),
+                                     jnp.arange(n_pages), planes, n_pages)
+    cache["tail_k"] = jnp.asarray(rng.standard_normal((b, 8, hkv, hd)).astype(np.float32))
+    cache["tail_v"] = jnp.asarray(rng.standard_normal((b, 8, hkv, hd)).astype(np.float32))
     q = jnp.asarray(rng.standard_normal((b, 1, hkv * n_rep, hd)).astype(np.float32))
     pos = jnp.asarray([depth - 1, 14], jnp.int32)  # per-row watermarks
     table = np.zeros((b, 8), np.int32)  # 64-token capacity, partly occupied
@@ -102,10 +110,11 @@ def test_attend_paged_tile_width_invariance():
             table[i, j] = int(perm[(i * 4 + j) % n_pages])
     table = jnp.asarray(table)
 
+    paged = dict(layer=layer, n_pages=n_pages)
     ref = KV.attend_compressed(q, cache, pos, keep, kv_block=16,
-                               block_table=table)
+                               block_table=table, **paged)
     outs = [fa_ops.attend_with_tail(q, cache, pos, block_table=table,
-                                    pages_per_tile=g)
+                                    pages_per_tile=g, keep=keep, **paged)
             for g in (1, 2, 4, 8)]
     for g, out in zip((1, 2, 4, 8), outs):
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -118,7 +127,7 @@ def test_attend_paged_tile_width_invariance():
     # row's watermark (max pos 28 -> 3 flushed pages + tail)
     sliced = KV.table_view(table, 4)
     out_sl = fa_ops.attend_with_tail(q, cache, pos, block_table=sliced,
-                                     pages_per_tile=2)
+                                     pages_per_tile=2, keep=keep, **paged)
     np.testing.assert_array_equal(np.asarray(out_sl), np.asarray(outs[1]))
 
 

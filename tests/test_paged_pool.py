@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from repro.core import kv_cache as KV
+from repro.core import page_rows
 from repro.models import api as model_api
 from repro.serve import engine as E
 
@@ -66,14 +67,13 @@ def test_paged_update_and_attend_match_dense(lm):
         "tail_k": jnp.zeros((b, 8, hkv, hd), jnp.float32),
         "tail_v": jnp.zeros((b, 8, hkv, hd), jnp.float32),
     }
-    paged = {
-        "packed_k": jnp.zeros((n_pages, hkv, nh, keep, keep), jnp.int8),
-        "scale_k": jnp.zeros((n_pages, hkv, nh), jnp.float32),
-        "packed_v": jnp.zeros((n_pages, hkv, nh, keep, keep), jnp.int8),
-        "scale_v": jnp.zeros((n_pages, hkv, nh), jnp.float32),
-        "tail_k": jnp.zeros((b, 8, hkv, hd), jnp.float32),
-        "tail_v": jnp.zeros((b, 8, hkv, hd), jnp.float32),
-    }
+    # the page pool: one layer of row storage (core.page_rows) + tails
+    layout = page_rows.row_layout("dct", keep, hkv, hd)
+    pool = page_rows.zeros(layout, 1, n_pages)
+    assert pool["rows_codes"].shape == (1, n_pages, 1, 128)
+    assert pool["rows_scale"].shape == (1, 1, 1, 128)  # 16 pages a row
+    tails = {"tail_k": jnp.zeros((b, 8, hkv, hd), jnp.float32),
+             "tail_v": jnp.zeros((b, 8, hkv, hd), jnp.float32)}
     # scrambled host allocation: page for (row, block) in arbitrary order
     perm = rng.permutation(n_pages)
     page_of = {(i, j): int(perm[(i * 4 + j) % n_pages])
@@ -90,31 +90,80 @@ def test_paged_update_and_attend_match_dense(lm):
                 table[i, t // 8] = fp[i]
         else:
             fp = np.full((b,), n_pages, np.int32)
-        paged = KV.update_layer(paged, kn, vn, posv, keep,
-                                flush_page=jnp.asarray(fp))
+        pool, tails = KV.update_layer_paged(pool, jnp.int32(0), tails, kn, vn,
+                                            posv, jnp.asarray(fp), keep,
+                                            n_pages=n_pages)
 
     # every flushed dense block is bitwise present in its mapped page
+    pages = page_rows.gather_planes(layout, pool, jnp.arange(n_pages),
+                                    n_pages, layer=0)
+    assert pages["packed_k"].shape == (n_pages, hkv, nh, keep, keep)
+    assert pages["scale_k"].shape == (n_pages, hkv, nh)
     for i in range(b):
         for j in range(depth // 8):
-            np.testing.assert_array_equal(
-                np.asarray(dense["packed_k"][i, j]),
-                np.asarray(paged["packed_k"][table[i, j]]), err_msg=f"{i},{j}")
+            for name in ("packed_k", "scale_k", "packed_v", "scale_v"):
+                np.testing.assert_array_equal(
+                    np.asarray(dense[name][i, j]),
+                    np.asarray(pages[name][table[i, j]]),
+                    err_msg=f"{name} {i},{j}")
     np.testing.assert_array_equal(np.asarray(dense["tail_k"]),
-                                  np.asarray(paged["tail_k"]))
+                                  np.asarray(tails["tail_k"]))
 
     q = jnp.asarray(rng.standard_normal((b, 1, h, hd)).astype(np.float32))
     posq = jnp.full((b,), depth - 1, jnp.int32)
+    paged = dict(pool, **tails)
     out_dense = KV.attend_compressed(q, dense, posq, keep, kv_block=16)
     out_paged = KV.attend_compressed(q, paged, posq, keep, kv_block=16,
-                                     block_table=jnp.asarray(table))
+                                     block_table=jnp.asarray(table),
+                                     layer=0, n_pages=n_pages)
     np.testing.assert_array_equal(np.asarray(out_dense), np.asarray(out_paged))
 
     # fused kernel path (interpret): block table on the scalar-prefetch side
     from repro.kernels.fused_attend import ops as fa_ops
     out_kern = fa_ops.attend_with_tail(q, paged, posq,
-                                       block_table=jnp.asarray(table))
+                                       block_table=jnp.asarray(table),
+                                       keep=keep, layer=0, n_pages=n_pages)
     np.testing.assert_allclose(np.asarray(out_kern), np.asarray(out_dense),
                                atol=1e-4)
+
+
+@pytest.mark.parametrize("keep,hd,hkv", [(6, 64, 2), (3, 128, 4)],
+                         ids=["keep6-hd64", "keep3-hd128-hkv4"])
+def test_attend_paged_reads_corners_past_skipped_row_ends(keep, hd, hkv):
+    """Layouts whose corners do not tile a 128-word row (12 and 3 words)
+    start a corner that would cross a row's end on the next row: the fused
+    kernel (interpreted) still reads every page as the reference gather
+    does."""
+    from repro.kernels.fused_attend import ops as fa_ops
+
+    b, n_rep, n_pages, layer, n_layers = 2, 2, 7, 1, 2
+    nh = hd // 8
+    layout = page_rows.row_layout("dct", keep, hkv, hd)
+    assert layout.group("rows_codes").words > 2 * hkv * nh * keep * \
+        -(-keep // 4)                        # some row ends are skipped
+    rng = np.random.default_rng(keep + hd)
+    lead = (n_layers, n_pages, hkv, nh)
+    planes = {}
+    for sfx in ("_k", "_v"):
+        planes["packed" + sfx] = jnp.asarray(
+            rng.integers(-8, 8, lead + (keep, keep), np.int8))
+        planes["scale" + sfx] = jnp.asarray(
+            rng.uniform(0.5, 2, lead).astype(np.float32))
+    cache = page_rows.scatter_planes(
+        layout, page_rows.zeros(layout, n_layers, n_pages),
+        jnp.asarray(rng.permutation(n_pages), jnp.int32), planes, n_pages)
+    cache["tail_k"] = jnp.asarray(rng.standard_normal((b, 8, hkv, hd)).astype(np.float32))
+    cache["tail_v"] = jnp.asarray(rng.standard_normal((b, 8, hkv, hd)).astype(np.float32))
+    q = jnp.asarray(rng.standard_normal((b, 1, hkv * n_rep, hd)).astype(np.float32))
+    pos = jnp.asarray([29, 17], jnp.int32)
+    table = jnp.asarray(rng.integers(0, n_pages, (b, 4)), jnp.int32)
+
+    paged = dict(layer=layer, n_pages=n_pages)
+    ref = KV.attend_compressed(q, cache, pos, keep, kv_block=16,
+                               block_table=table, **paged)
+    out = fa_ops.attend_with_tail(q, cache, pos, block_table=table,
+                                  pages_per_tile=2, keep=keep, **paged)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -138,6 +187,29 @@ def test_paged_engine_bitwise_matches_dense(lm, plan):
         assert a.out_tokens == b.out_tokens, (a.uid, a.out_tokens, b.out_tokens)
     # the whole pool is free again after the workload drains
     assert sorted(paged._free_pages) == list(range(32))
+
+
+@pytest.mark.slow
+def test_paged_engine_keep6_hd64_matches_dense_through_kernel():
+    """At keep 6 and head_dim 64 a page's corners (12 words each) skip the
+    end of every code row.  Greedy tokens through the fused paged kernel
+    (interpreted) are the dense pool's on the same kernel backend."""
+    import dataclasses
+
+    from repro.configs.base import get_config
+
+    cfg = dataclasses.replace(get_config("qwen2_0_5b").reduced(), head_dim=64)
+    api = model_api.build("qwen2_0_5b", cfg)
+    params = api.init(jax.random.PRNGKey(0), dtype=jnp.float32)
+    kw = dict(max_seq=32, kv_compress=True, kv_keep=6, codec_backend="pallas")
+    reqs = lambda: _requests(4)
+    dense = E.Engine(api, params, E.ServeConfig(**kw), batch=2).generate(reqs())
+    paged = E.Engine(api, params, E.ServeConfig(**kw, pool_pages=12), batch=2)
+    layout = page_rows.row_layout("dct", 6, cfg.n_kv_heads, 64)
+    assert layout.group("rows_codes").rows_per_page == 4   # 384 words -> 4 rows
+    got = paged.generate(reqs())
+    assert paged.paged
+    assert [r.out_tokens for r in got] == [r.out_tokens for r in dense]
 
 
 @pytest.mark.slow

@@ -190,11 +190,34 @@ def test_tiered_and_shared_parity_small(lm_small):
     """qwen2-reduced: device pool of 4 pages + host tier + prefix sharing
     serves the mixed workload bitwise-identically to a big untiered pool,
     with the ledger checked after every admission/retirement."""
+    _tiered_and_shared_parity(lm_small)
+
+
+def test_donated_pool_tiered_and_shared_parity_small(lm_small, monkeypatch):
+    """The same with the decode step donating (consuming) its pool, as the
+    engine builds it on a TPU: park, resume, prefix verification and reset
+    never touch a pool already handed to a step, and tokens are unchanged."""
+    real_jit, donated = jax.jit, []
+
+    def jit(fn, *args, **kw):
+        donated.append(kw.get("donate_argnums", ()))
+        return real_jit(fn, *args, **kw)
+
+    def on_tpu():
+        monkeypatch.setattr(E.jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(E.jax, "jit", jit)
+
+    _tiered_and_shared_parity(lm_small, before_engine=on_tpu)
+    assert (2,) in donated
+
+
+def _tiered_and_shared_parity(lm_small, before_engine=lambda: None):
     api, params = lm_small
     kw = dict(max_seq=64, kv_compress=True, kv_keep=8,
               codec_backend="reference")
     base = E.Engine(api, params, E.ServeConfig(**kw, pool_pages=32),
                     batch=4).generate(_requests())
+    before_engine()
     eng = E.Engine(api, params,
                    E.ServeConfig(**kw, pool_pages=4, host_pool_pages=32,
                                  prefix_sharing=True), batch=4)

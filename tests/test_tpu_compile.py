@@ -7,13 +7,16 @@ second each.  The topology is described inside a module fixture, never at
 import time: one process at a time may load the TPU library, and every
 test worker imports this file.  Nothing here runs on a device.
 """
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import page_rows
 from repro.kernels.fused_attend.kernel import attend_paged
 from repro.kernels.fused_compress.kernel import (compress_plane_pallas,
                                                  decompress_plane_pallas)
@@ -53,23 +56,29 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("keep", [4, 8])
+LAYERS = 3  # layers of the stacked pool the kernel reads one of
+
+
+def _attend(arch, keep, one_chip):
+    """Compile `attend_paged` over a stacked row pool at served widths."""
+    hd, hkv, n_rep = WIDTHS[arch]
+    layout = page_rows.row_layout("dct", keep, hkv, hd)
+    shapes = layout.shapes(LAYERS, PAGES)
+    return _compile(
+        lambda pos, bt, layer, codes, scales, q, tk, tv: attend_paged(
+            pos, bt, layer, codes, scales, q, tk, tv, layout=layout,
+            n_pages=PAGES, pages_per_tile=G, interpret=False),
+        one_chip,
+        ((B,), jnp.int32), ((B, MAX_SEQ // 8), jnp.int32), ((), jnp.int32),
+        shapes[page_rows.CODES], shapes["rows_scale"],
+        ((B, hkv, n_rep, hd), jnp.bfloat16),
+        ((B, 8, hkv, hd), jnp.bfloat16), ((B, 8, hkv, hd), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("keep", [3, 4, 6, 8])
 @pytest.mark.parametrize("arch", sorted(WIDTHS))
 def test_attend_paged_compiles(one_chip, arch, keep):
-    hd, hkv, n_rep = WIDTHS[arch]
-    nh = hd // 8
-    pool = (PAGES, hkv, nh, keep, keep)
-    compiled = _compile(
-        lambda pk, sk, pv, sv, q, pos, bt, tk, tv: attend_paged(
-            pk, sk, pv, sv, q, pos, bt, tk, tv, pages_per_tile=G,
-            interpret=False),
-        one_chip,
-        (pool, jnp.int8), ((PAGES, hkv, nh), jnp.float32),
-        (pool, jnp.int8), ((PAGES, hkv, nh), jnp.float32),
-        ((B, hkv, n_rep, hd), jnp.bfloat16), ((B,), jnp.int32),
-        ((B, MAX_SEQ // 8), jnp.int32),
-        ((B, 8, hkv, hd), jnp.bfloat16), ((B, 8, hkv, hd), jnp.bfloat16))
-    _assert_kernel(compiled)
+    _assert_kernel(_attend(arch, keep, one_chip))
 
 
 def _planes():
@@ -106,15 +115,7 @@ def test_kernels_carry_their_names(one_chip):
     a profile's op events name it."""
     import re
 
-    hd, hkv, n_rep = WIDTHS["qwen2_0_5b"]
-    pool = (PAGES, hkv, hd // 8, 4, 4)
-    attend = _compile(
-        lambda pk, sk, q, pos, bt, t: attend_paged(
-            pk, sk, pk, sk, q, pos, bt, t, t, pages_per_tile=G,
-            interpret=False),
-        one_chip, (pool, jnp.int8), ((PAGES, hkv, hd // 8), jnp.float32),
-        ((B, hkv, n_rep, hd), jnp.bfloat16), ((B,), jnp.int32),
-        ((B, MAX_SEQ // 8), jnp.int32), ((B, 8, hkv, hd), jnp.bfloat16))
+    attend = _attend("qwen2_0_5b", 4, one_chip)
     r, c = _planes()[0]
     codec = _compile(
         lambda x: decompress_plane_pallas(
@@ -151,3 +152,71 @@ def test_qwen2_paged_decode_step_fits_one_v5e(one_chip, monkeypatch):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < 4 * 2**30, mem  # ~1 GB of bf16 weights + pool + temps
+
+
+# the benchmark cells' pools: (batch, pool pages) at the top decode bucket
+SERVED = {"qwen2_0_5b": (32, 8192), "yi_6b": (16, 1792)}
+SERVED_SEQ = 2048
+
+
+@pytest.mark.parametrize("arch", sorted(SERVED))
+def test_paged_decode_step_updates_pool_in_place(one_chip, monkeypatch, arch):
+    """The whole paged decode step of each served model, at its benchmark
+    batch and pool and the top decode bucket, with the pool donated as the
+    engine donates it: the stacked page rows are written (`page_write`) and
+    read in place, so no `copy` (or `copy-start`) in the program has a
+    result with a pool row dimension, and every row array is written by a
+    `page_write` call that keeps it in HBM; and `attend_paged` is the
+    custom call whose first two operands are the s32[B] positions and the
+    s32[B, nblocks] table."""
+    from repro.configs.base import get_config
+    from repro.models import api as model_api
+    from repro.serve import engine as E
+
+    monkeypatch.setenv("REPRO_CODEC_INTERPRET", "0")  # this host is a CPU
+    batch, pages = SERVED[arch]
+    cfg = get_config(arch)
+    api = model_api.build(arch, cfg)
+    sc = E.ServeConfig(max_seq=SERVED_SEQ, kv_compress=True, kv_keep=4,
+                       codec_backend="pallas", pool_pages=pages)
+    prefill_fn, decode_fn, cache_init, _ = E.make_steps(api, sc)
+    _, step_fn = E.make_fused_steps(prefill_fn, decode_fn, sc, paged=True)
+    place = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+    params = place(jax.eval_shape(api.init, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: cache_init(batch)))
+    vec = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
+    nblocks = SERVED_SEQ // 8
+    step = jax.jit(functools.partial(step_fn, attend_blocks=nblocks),
+                   donate_argnums=(2,))
+    text = step.lower(params, vec, cache, vec, vec).compile().as_text()
+
+    row_dims = {seg.planes[n].shape[1] for seg in cache.segments
+                for n in seg.row_keys}
+    assert row_dims == ({8192, 2048} if arch == "qwen2_0_5b"
+                        else {7168, 1792}), row_dims
+    copies = re.findall(r"%(\S+) = (.*?) copy(?:-start)?\(", text)
+    assert copies, "no copy at all: the pattern no longer reads this HLO"
+    pool_copies = [(n, shape) for n, shape in copies
+                   if row_dims & {int(d) for d in re.findall(
+                       r"\[([\d,]+)\]", shape) for d in d.split(",")}]
+    assert not pool_copies, pool_copies
+    writes = set(re.findall(r"%page_write(?:\.\d+)? = (\w+\[[\d,]+\]\S*) "
+                            r"custom-call\(", text))
+    hlo_type = {"int32": "s32", "float32": "f32"}
+    pools = {hlo_type[jnp.dtype(a.dtype).name]
+             + f"[{','.join(map(str, a.shape))}]"
+             for seg in cache.segments
+             for a in (seg.planes[n] for n in seg.row_keys)}
+    assert {w.split("{")[0] for w in writes} == pools, (writes, pools)
+    assert not any("S(" in w for w in writes), writes   # HBM, not VMEM
+
+    shape_of = dict(re.findall(r"%(\S+) = (\S+) ", text))
+    calls = re.findall(r"%attend_paged(?:\.\d+)? = \S+ custom-call\("
+                       r"%(\S+), %(\S+),", text)
+    assert calls, "no attend_paged custom call"
+    for pos, table in calls:
+        assert shape_of[pos].startswith(f"s32[{batch}]"), shape_of[pos]
+        assert shape_of[table].startswith(f"s32[{batch},{nblocks}]"), \
+            shape_of[table]
