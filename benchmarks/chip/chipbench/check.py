@@ -1,7 +1,8 @@
 """The comparison that decides `correct`.
 
 A sample of the requests the window finished, drawn from the seed, runs
-through the reference once, teacher-forced on prompt + served tokens.  It
+through the reference (the configuration's block, `final_hidden` and
+`logits_rows`) once, teacher-forced on prompt + served tokens.  It
 holds the longest finished request, compared over all its served tokens
 (the deepest contexts and decode buckets), and `per_half` requests from
 each half of the engine's slots, each compared over its first
@@ -57,7 +58,7 @@ def halves(picked: list, slot_of: dict, batch: int) -> tuple[int, int]:
     return n[0], n[1]
 
 
-def _gaps(params, dims, keep, prompt, served, precision):
+def _gaps(block, params, dims, keep, prompt, served, precision):
     """Per served position: (ref best, ref at served, ref at control's
     first choice or None)."""
     import jax.numpy as jnp
@@ -69,37 +70,36 @@ def _gaps(params, dims, keep, prompt, served, precision):
     toks[:s] = seq
     dkey = tuple(sorted(dims.items()))
     rows = slice(plen - 1, plen - 1 + n)
-    h = ref.final_hidden(params, jnp.asarray(toks), plen, d=dkey, keep=keep,
-                         precision="reference")[rows]
+    h = block.final_hidden(params, jnp.asarray(toks), plen, d=dkey,
+                           keep=keep, precision="reference")[rows]
     hc = None
     if precision == "control":
-        hc = ref.final_hidden(params, jnp.asarray(toks), plen, d=dkey,
-                              keep=keep, precision="control")[rows]
+        hc = block.final_hidden(params, jnp.asarray(toks), plen, d=dkey,
+                                keep=keep, precision="control")[rows]
     best, at_served, at_ctrl = [], [], []
     served = np.asarray(served)
     for c0 in range(0, n, ROW_CHUNK):
         c1 = min(n, c0 + ROW_CHUNK)
-        lg = np.asarray(ref.logits_rows(params, h[c0:c1], tied=dims["tied"],
-                                        precision="reference"))
+        lg = np.asarray(block.logits_rows(params, h[c0:c1], d=dkey,
+                                          precision="reference"))
         best.append(lg.max(-1))
         at_served.append(lg[np.arange(c1 - c0), served[c0:c1]])
         if hc is not None:
-            lc = np.asarray(ref.logits_rows(params, hc[c0:c1],
-                                            tied=dims["tied"],
-                                            precision="control"))
+            lc = np.asarray(block.logits_rows(params, hc[c0:c1], d=dkey,
+                                              precision="control"))
             at_ctrl.append(lg[np.arange(c1 - c0), lc.argmax(-1)])
     best = np.concatenate(best)
     return (best - np.concatenate(at_served),
             best - np.concatenate(at_ctrl) if hc is not None else None)
 
 
-def readings(params, dims: dict, keep: int, picked: list,
+def readings(block, params, dims: dict, keep: int | None, picked: list,
              control: bool = False) -> dict:
     """Widest gaps over the sampled (request, tokens) pairs (and each
     request's own widest, `request_gaps`; the control's likewise)."""
     per_request, per_ctrl, n_tok, n_off = [], [], 0, 0
     for r, n in picked:
-        g, gc = _gaps(params, dims, keep, np.asarray(r.prompt),
+        g, gc = _gaps(block, params, dims, keep, np.asarray(r.prompt),
                       r.out_tokens[:n], "control" if control else "reference")
         per_request.append(float(g.max()))
         n_tok += len(g)

@@ -8,8 +8,11 @@ kernel in the trace); the harness then leaves the metric out of the line.
   window_s          host seconds of the measured window
   stats0, stats1    `Engine.stats` at the window's two edges
   work              `flops.window_work` of the tokens served in the window
-  dims, keep        model sizes (`spec.model_dims`) and the codec's keep
-  peaks             `peaks.peaks_for(device_kind)`
+  block, dims       the configuration's block module and its `model_dims`
+  keep              the codec's keep, or None where the cell keeps raw K/V
+  chips             chips the cell serves on (a share of a peak is of
+                    chips x the one-chip peak)
+  peaks             `peaks.peaks_for(device_kind)`, of one chip
   trace             None, or {"planes": [trace.Plane], "t0", "t1"
                     (the traced window on the trace's clock, ns),
                     "window_s"}

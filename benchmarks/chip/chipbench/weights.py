@@ -1,6 +1,7 @@
 """Random bf16 weights from the seed, made on the device in one jitted call.
 
-The tree has the layout the serve engine reads (a decoder whose layers are
+The tree has the layout the serve engine reads, as the configuration's
+block gives it (`layout(d)`: path -> (shape, kind, fan_in), layer leaves
 stacked on a leading axis).  The reference reads the same leaves, made anew
 from the same seed after the program's state is freed.
 
@@ -30,31 +31,6 @@ def _leaf_key(key, path: str):
     return jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
 
 
-def layout(d: dict) -> dict:
-    """path -> (shape, kind, fan_in) of every leaf; layer leaves are stacked."""
-    L, D, F, V = d["n_layers"], d["d_model"], d["d_ff"], d["vocab"]
-    hq, hkv = d["n_heads"] * d["head_dim"], d["n_kv_heads"] * d["head_dim"]
-    leaves = {
-        "embed": ((V, D), "embed", None),
-        "final_norm/g": ((D,), "gain", None),
-        "layers/ln1/g": ((L, D), "gain", None),
-        "layers/ln2/g": ((L, D), "gain", None),
-        "layers/attn/wq/w": ((L, D, hq), "proj", D),
-        "layers/attn/wk/w": ((L, D, hkv), "proj", D),
-        "layers/attn/wv/w": ((L, D, hkv), "proj", D),
-        "layers/attn/wo/w": ((L, hq, D), "proj", hq),
-        "layers/mlp/wg/w": ((L, D, F), "proj", D),
-        "layers/mlp/wu/w": ((L, D, F), "proj", D),
-        "layers/mlp/wd/w": ((L, F, D), "proj", F),
-    }
-    if d["qkv_bias"]:
-        for n, width in (("wq", hq), ("wk", hkv), ("wv", hkv)):
-            leaves[f"layers/attn/{n}/b"] = ((L, width), "bias", None)
-    if not d["tied"]:
-        leaves["lm_head"] = ((D, V), "head", None)
-    return leaves
-
-
 def _draw(key, shape, kind, fan_in):
     z = jax.random.normal(key, shape, jnp.float32)
     if kind == "proj":
@@ -66,9 +42,9 @@ def _draw(key, shape, kind, fan_in):
     return z.astype(DTYPE)
 
 
-def _make(key, d):
+def _make(key, leaves: dict):
     out = {}
-    for path, (shape, kind, fan_in) in layout(d).items():
+    for path, (shape, kind, fan_in) in leaves.items():
         k = _leaf_key(key, path)
         if path.startswith("layers/"):
             # one layer at a time, so no f32 copy of a whole stack exists
@@ -84,13 +60,21 @@ def _make(key, d):
     return out
 
 
-def make_params(d: dict, seed: int) -> dict:
-    """The weight tree on the default device, made by one jitted call."""
-    fn = jax.jit(lambda key: _make(key, d))
-    params = fn(base_key(seed))
+def make_params(leaves: dict, seed: int, mesh=None) -> dict:
+    """The weight tree of a block's `layout`, made by one jitted call: on
+    the default device, or laid out over `mesh` as the serve engine places
+    its weights."""
+    fn = lambda key: _make(key, leaves)
+    shardings = None
+    if mesh is not None:
+        from repro.parallel import sharding as sh
+
+        shardings = sh.param_shardings(jax.eval_shape(fn, base_key(0)), mesh,
+                                       fsdp=False)
+    params = jax.jit(fn, out_shardings=shardings)(base_key(seed))
     jax.block_until_ready(params)
     return params
 
 
-def param_bytes(d: dict) -> int:
-    return sum(int(np.prod(s)) * 2 for s, _, _ in layout(d).values())
+def param_bytes(leaves: dict) -> int:
+    return sum(int(np.prod(s)) * 2 for s, _, _ in leaves.values())

@@ -4,9 +4,10 @@
         --workload <cell> [--rows N]
 
 Compiles the admission step at (rows x top prefill bucket) -- rows defaults
-to the cell's batch -- and the decode step at the top decode bucket, with
-the compiled pallas kernels, for one chip of a `v5e:2x2` topology, and
-prints each program's `memory_analysis()` beside the weights' size.  What
+to the cell's batch -- and the decode step (at the top decode bucket where
+the cell keeps a page pool), with the compiled pallas kernels, for one chip
+of a `v5e:2x2` topology, and prints each program's `memory_analysis()`
+beside the weights' size.  One-chip cells only.  What
 the chip's compiler refuses here costs no chip time.
 """
 from __future__ import annotations
@@ -40,33 +41,30 @@ def main(argv=None):
     import run
     from chipbench import spec, weights
     from repro.core import kv_cache as kvc
-    from repro.models import api as model_api
     from repro.serve import engine as eng
 
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
     cell = spec.load_cell(args.workload)
+    if cell.chips != 1:
+        raise SystemExit(f"{args.workload}: rehearse.py compiles one-chip "
+                         "cells only")
     s = cell.serve
-    dims = spec.model_dims(cell.config)
-    cfg = run.arch_config(cell)
-    api = model_api.build(cell.config["arch"], cfg)
-    sc = eng.ServeConfig(max_seq=s["max_seq"], kv_compress=True,
-                         kv_keep=s["keep"], codec_backend="pallas",
-                         pool_pages=s["pool_pages"],
-                         prefill_buckets=tuple(s["prefill_buckets"]),
-                         decode_buckets=tuple(s["decode_buckets"]))
+    leaves = cell.block.layout(cell.dims)
+    api = cell.block.program(cell.config)
+    sc = run.serve_config(cell, "pallas")
     prefill_fn, decode_fn, cache_init, _ = eng.make_steps(api, sc)
     admit_fn, step_fn = eng.make_fused_steps(prefill_fn, decode_fn, sc,
-                                             paged=True)
+                                             paged=sc.paged)
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
-    params = jax.eval_shape(lambda: weights._make(weights.base_key(0), dims))
+    params = jax.eval_shape(lambda: weights._make(weights.base_key(0), leaves))
     params = jax.tree.map(lambda a: sds(a.shape, a.dtype), params)
     batch = s["batch"]
     rows = args.rows or batch
     top = max(s["prefill_buckets"])
     gb = 2 ** 30
-    print(f"{args.workload}: weights {weights.param_bytes(dims) / gb:.3f} GiB")
+    print(f"{args.workload}: weights {weights.param_bytes(leaves) / gb:.3f} GiB")
 
     def show(name, compiled):
         m = compiled.memory_analysis()
@@ -81,9 +79,14 @@ def main(argv=None):
     pool = jax.eval_shape(lambda: cache_init(batch))
     pool = jax.tree.map(lambda a: sds(a.shape, a.dtype), pool)
     vec = sds((batch,), jnp.int32)
-    dtop = max(s["decode_buckets"])
-    step = jax.jit(functools.partial(step_fn, attend_blocks=dtop // kvc.BLOCK))
-    show(f"decode bucket {dtop}", step.lower(params, vec, pool, vec, vec).compile())
+    if sc.paged:
+        dtop = max(s["decode_buckets"])
+        step = jax.jit(functools.partial(step_fn,
+                                         attend_blocks=dtop // kvc.BLOCK))
+        show(f"decode bucket {dtop}",
+             step.lower(params, vec, pool, vec, vec).compile())
+    else:
+        show("decode", jax.jit(step_fn).lower(params, vec, pool, vec).compile())
     pool_b = sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
                  for a in jax.tree.leaves(pool))
     print(f"pool {pool_b / gb:.3f} GiB")

@@ -3,13 +3,16 @@
     python3 benchmarks/chip/run.py --workload <cell> --seed <n> \
         --seconds <s> --trace <0|1>
 
-In one process, on one TPU:
+In one process, on the cell's TPU chips:
   1. exit non-zero unless JAX finds a TPU (and as many chips as the cell
      asks for);
   2. turn on JAX's persistent compile cache at `<checkout>/.jax_cache`;
-  3. make the configuration's bf16 weights on the device from --seed;
-  4. build the serve `Engine` with AOT warmup over the cell's own prefill
-     and decode ladders -- everything up to here is `setup_s`;
+  3. make the configuration's bf16 weights on the device from --seed, in
+     the layout of its block (`chipbench/blocks/`), laid out over the
+     cell's `serve.mesh` where it asks for more than one chip;
+  4. build the serve `Engine` (the paged dct pool, or raw K/V where
+     `serve.kv_compress` is false) with AOT warmup over the cell's own
+     prefill and decode ladders -- everything up to here is `setup_s`;
   5. queue the cell's seeded requests and run `Engine.generate` on a
      worker thread;
   6. open the window once `open_after` requests have retired, close it
@@ -69,69 +72,55 @@ def device_info(jax, chips: int, platform: str = "tpu") -> dict:
             "count": chips}
 
 
-def memory_peak_bytes(jax, chips: int) -> tuple[int, dict]:
-    """Peak HBM of the fullest chip: the allocator's peak of buffers in use
-    plus its peak of memory reserved for programs' temporaries, which the
+def memory_peak_bytes(jax, chips: int) -> tuple[list, dict]:
+    """Peak HBM of each chip: the allocator's peak of buffers in use plus
+    its peak of memory reserved for programs' temporaries, which the
     runtime reserves apart from the buffers (a program whose temporaries do
-    not fit fails to load)."""
+    not fit fails to load); and the fullest chip's allocator stats."""
     stats = [d.memory_stats() or {} for d in jax.devices()[:chips]]
     peaks = [int(s.get("peak_bytes_in_use", 0))
              + int(s.get("peak_bytes_reserved", 0)) for s in stats]
-    i = max(range(len(peaks)), key=peaks.__getitem__)
-    return peaks[i], stats[i]
+    return peaks, stats[max(range(chips), key=peaks.__getitem__)]
 
 
 # ---------------------------------------------------------------------------
 # The system under test
 # ---------------------------------------------------------------------------
 
-def arch_config(cell):
-    """The program's config at the cell's configuration file's sizes."""
-    import dataclasses
-
-    from chipbench.spec import model_dims
-    from repro.configs.base import get_config
-
-    d = model_dims(cell.config)
-    base = get_config(cell.config["arch"])
-    cfg = dataclasses.replace(
-        base, n_layers=d["n_layers"], d_model=d["d_model"],
-        n_heads=d["n_heads"], n_kv_heads=d["n_kv_heads"],
-        head_dim=d["head_dim"], d_ff=d["d_ff"], vocab_size=d["vocab"],
-        rope_theta=d["rope_theta"], qkv_bias=d["qkv_bias"],
-        tie_embeddings=d["tied"])
-    if cfg.family != "dense" or cfg.attn_type != "gqa":
-        raise RunError(f"{cell.config['arch']}: the harness serves dense "
-                       "GQA decoders only")
-    return cfg
-
-
-def build_engine(cell, cfg, params, require_compiled: bool):
-    from repro.codec import dispatch
-    from repro.models import api as model_api
-    from repro.serve.engine import Engine, ServeConfig
+def serve_config(cell, backend: str | None, mesh=None):
+    """The engine's `ServeConfig` as the cell's `serve` section gives it:
+    the paged dct pool, or raw bf16 K/V where `kv_compress` is false."""
+    from repro.serve.engine import ServeConfig
 
     s = cell.serve
-    backend = s.get("codec_backend") if require_compiled else None
-    if s["codec"] != "dct":
-        raise RunError(f"codec {s['codec']!r}: the cells run the dct family")
-    sc = ServeConfig(
-        max_seq=s["max_seq"], kv_compress=True, kv_keep=s["keep"],
-        codec_backend=backend,
-        pool_pages=s["pool_pages"], prefill_buckets=tuple(s["prefill_buckets"]),
-        decode_buckets=tuple(s["decode_buckets"]), aot_warmup=True)
-    if require_compiled:
+    kw = dict(max_seq=s["max_seq"], mesh=mesh, aot_warmup=True,
+              prefill_buckets=tuple(s["prefill_buckets"]))
+    if cell.keep is None:
+        return ServeConfig(kv_compress=False, **kw)
+    return ServeConfig(kv_compress=True, kv_keep=cell.keep,
+                       codec_backend=backend, pool_pages=s["pool_pages"],
+                       decode_buckets=tuple(s["decode_buckets"]), **kw)
+
+
+def build_engine(cell, params, require_compiled: bool, mesh=None):
+    from repro.codec import dispatch
+    from repro.serve.engine import Engine
+
+    backend = cell.serve.get("codec_backend") if require_compiled else None
+    sc = serve_config(cell, backend, mesh)
+    if require_compiled and sc.kv_compress:
         name = dispatch.resolve_backend_name(sc.codec_backend)
         if name != "pallas" or dispatch.resolve_interpret():
             raise RunError(f"codec backend {name!r} (interpret="
                            f"{dispatch.resolve_interpret()}): the cell must "
                            "run the compiled pallas kernels")
-    api = model_api.build(cell.config["arch"], cfg)
-    return Engine(api, params, sc, batch=s["batch"])
+    return Engine(cell.block.program(cell.config), params, sc,
+                  batch=cell.serve["batch"])
 
 
 def plant_fault(engine, fault: str, vocab: int) -> None:
     """Break the timed decode step underneath the engine (tests only)."""
+    import jax
     import jax.numpy as jnp
 
     if fault not in FAULTS:
@@ -139,18 +128,24 @@ def plant_fault(engine, fault: str, vocab: int) -> None:
 
     def wrap(fn):
         def step(params, token, cache, pos, *rest, **kw):
+            if fault == "state":      # the step hands its state back unchanged:
+                # a copy taken before the step, which may donate its input
+                before = jax.tree.map(jnp.copy, cache)
             tok, pos1, new_cache = fn(params, token, cache, pos, *rest, **kw)
             if fault == "token":      # a token altered where it is produced
                 tok = (tok + 1) % vocab
-            elif fault == "state":    # the step hands its state back unchanged
-                new_cache = cache
+            elif fault == "state":
+                new_cache = before
             elif fault == "half_batch":  # half of the rows left out
                 rows = jnp.arange(tok.shape[0])
                 tok = jnp.where(rows >= tok.shape[0] // 2, token, tok)
             return tok, pos1, new_cache
         return step
 
-    engine._decode_fns = {t: wrap(f) for t, f in engine._decode_fns.items()}
+    engine._decode = wrap(engine._decode)
+    if engine.paged:
+        engine._decode_fns = {t: wrap(f)
+                              for t, f in engine._decode_fns.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -230,17 +225,18 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
 
     from chipbench import check, flops, metrics, peaks, traffic, weights
     from chipbench.latency import itl_between, itl_mark, observe_slots
-    from chipbench.spec import model_dims
+    from repro.parallel.mesh import make_serve_mesh
     from repro.serve.engine import Request
 
-    chips = cell.workload["chips"]
+    chips, block = cell.chips, cell.block
     dev = device_info(jax, chips, platform)
     pk = peaks.peaks_for(dev["kind"]) if platform == "tpu" else None
-    dims = model_dims(cell.config)
-    keep = cell.serve["keep"]
-    cfg = arch_config(cell)
-    params = weights.make_params(dims, seed)
-    engine = build_engine(cell, cfg, params, require_compiled=platform == "tpu")
+    dims, keep = cell.dims, cell.keep
+    mesh = make_serve_mesh(cell.serve["mesh"]) if chips > 1 else None
+    leaves = block.layout(dims)
+    params = weights.make_params(leaves, seed, mesh)
+    engine = build_engine(cell, params, require_compiled=platform == "tpu",
+                          mesh=mesh)
     if fault is not None:
         plant_fault(engine, fault, dims["vocab"])
     requests = [Request(uid=i, prompt=p, max_new=m) for i, (p, m) in
@@ -288,9 +284,11 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     new_traces = sum(engine.trace_counts.delta(traces0).values())
     finished = [r for r in requests if r.done]
     queued = sum(1 for r in requests if not r.out_tokens)
-    peak, mem = memory_peak_bytes(jax, chips)
+    chip_peaks, mem = memory_peak_bytes(jax, chips)
+    peak = max(chip_peaks)
     log("memory: " + json.dumps({k: mem.get(k) for k in (
-        "peak_bytes_in_use", "peak_bytes_reserved", "bytes_limit")}))
+        "peak_bytes_in_use", "peak_bytes_reserved", "bytes_limit")})
+        + f"; peak bytes per chip {chip_peaks}")
     spans = [(len(r.prompt), a, b) for r, a, b in zip(requests, out0, out1)
              if b > a]
 
@@ -311,20 +309,22 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         "itl_s": itl_between(engine, itl0, itl1),
         "memory_peak_bytes": peak,
         "stats0": stats0, "stats1": stats1,
-        "work": flops.window_work(dims, keep, spans),
-        "dims": dims, "keep": keep, "peaks": pk, "trace": None,
+        "work": flops.window_work(block, dims, keep, spans),
+        "block": block, "dims": dims, "keep": keep, "chips": chips,
+        "peaks": pk, "trace": None,
     }
     del engine, params
     gc.collect()
 
-    ref_params = weights.make_params(dims, seed)
+    ref_params = weights.make_params(leaves, seed)
     lim = cell.limits
     batch, per_half = cell.serve["batch"], lim["sample_per_half"]
     picked = check.sample(finished, seed, slot_of, batch, per_half,
                           lim["sample_tokens_per_request"])
     low, high = check.halves(picked, slot_of, batch)
     t_ref = time.perf_counter()
-    read = check.readings(ref_params, dims, keep, picked, control=control)
+    read = check.readings(block, ref_params, dims, keep, picked,
+                          control=control)
     read["reference_s"] = time.perf_counter() - t_ref
     del ref_params
 
@@ -357,6 +357,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         busy = [trace_lib.busy_ns(p.ops, tr["t0"], tr["t1"]) * 1e-9
                 for p in tr["planes"]]
         device["busy_s"] = sum(busy) / len(busy)
+        log(f"device busy s per chip: {busy}")
         device["window_s"] = tr["window_s"]
         where = trace_lib.idle_stretches(tr["planes"][0].ops, tr["t0"],
                                          tr["t1"])

@@ -10,11 +10,15 @@ layout) drives the whole of `run.run_cell` on the CPU, past the harness's
 look for a chip: once sound, where the program's reading must lie under
 its limit and the reference's float8 control's above it, and once per
 fault planted in the timed decode step, where `correct` must come out
-false.
+false.  Its siblings run the same way with raw K/V (`tiny_rawkv`), on a
+2x2 mesh of four virtual devices (`tiny_mesh`, in a child process), and
+with a block, configuration and workload that live only under
+`testdata/` (`tiny_ln_relu2`).
 """
 from __future__ import annotations
 
 import json
+import re
 import subprocess
 import sys
 import time
@@ -32,10 +36,16 @@ from chipbench import flops, spec, trace, traffic, weights  # noqa: E402
 
 E2E = [{"name": "tok_s", "unit": "tokens/s"}, {"name": "itl_p95_ms", "unit": "ms"},
        {"name": "setup_s", "unit": "s"}]
+LLAMA = spec.load_block("llama")
 
 
 def bench_json():
     return spec.load_json(ROOT / "BENCHMARK.json")
+
+
+def config_dims(name: str) -> dict:
+    config = spec.load_json(spec.CONFIG_DIR / f"{name}.json")
+    return spec.load_block(config["block"]).model_dims(config)
 
 
 # ---------------------------------------------------------------------------
@@ -135,7 +145,7 @@ def test_kv_bytes_match_the_plan(name, per_token):
     from repro.codec import plan as plan_lib
     from repro.configs.base import get_config
 
-    dims = spec.model_dims(spec.load_json(spec.CONFIG_DIR / f"{name}.json"))
+    dims = config_dims(name)
     page = plan_lib.as_plan(None, keep=4).page_bytes(get_config(name))
     assert flops.page_bytes(dims, 4) == page == 8 * per_token
 
@@ -145,20 +155,162 @@ def test_flop_count_by_hand():
          "head_dim": 2, "d_ff": 16, "vocab": 10}
     # per layer: q 8x8, k and v 8x4 each, o 8x8, gate/up/down 3 x 8x16
     per_layer = 64 + 32 + 32 + 64 + 3 * 128
-    assert flops.matmul_params(d) == 2 * per_layer + 8 * 10
+    assert LLAMA.matmul_params(d) == 2 * per_layer + 8 * 10
     # one query over 5 positions: 4 * 5 * heads * head_dim per layer
-    assert flops.attn_flops(d, 5) == 2 * 4 * 5 * 4 * 2
-    assert flops.token_flops(d, 5) == 2 * (2 * per_layer + 80) + 320
+    assert LLAMA.attn_flops(d, 5) == 2 * 4 * 5 * 4 * 2
+    assert flops.token_flops(LLAMA, d, 5) == 2 * (2 * per_layer + 80) + 320
     # a prompt of 3 admitted in the window, then decode tokens 1 and 2 at
     # positions 3 and 4 (no pool page read below position 8)
-    w = flops.window_work(d, 4, [(3, 0, 3)])
-    want = sum(flops.token_flops(d, c) for c in (1, 2, 3, 4, 5))
+    w = flops.window_work(LLAMA, d, 4, [(3, 0, 3)])
+    want = sum(flops.token_flops(LLAMA, d, c) for c in (1, 2, 3, 4, 5))
     assert w["model_flops"] == want
-    assert w["attn_flops"] == flops.attn_flops(d, 4) + flops.attn_flops(d, 5)
+    assert w["attn_flops"] == LLAMA.attn_flops(d, 4) + LLAMA.attn_flops(d, 5)
     assert w["kv_bytes"] == 0
     # a decode token at position 17 reads its two flushed pages
-    w = flops.window_work(d, 4, [(10, 8, 9)])
+    w = flops.window_work(LLAMA, d, 4, [(10, 8, 9)])
     assert w["kv_bytes"] == 2 * flops.page_bytes(d, 4)
+    # raw K/V: its 18 positions, K and V of 2 layers x 2 heads x 2 bf16
+    w = flops.window_work(LLAMA, d, None, [(10, 8, 9)])
+    assert w["kv_bytes"] == 18 * 2 * 2 * 2 * 2 * 2
+
+
+def test_raw_kv_bytes_match_the_program_cache():
+    """Raw K/V a position: 2 * layers * kv heads * head_dim * 2 bytes, as
+    the program's raw cache holds them (12,288 B for qwen2_0_5b)."""
+    sys.path.insert(0, str(ROOT / "src"))
+    import jax
+
+    cell = spec.load_cell("qwen2_0_5b.decode-heavy.rawkv")
+    api = cell.block.program(cell.config)
+    seq = 64
+    cache = jax.eval_shape(lambda: api.init_cache(1, seq))
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert flops.raw_position_bytes(cell.dims) == held // seq == 12288
+
+
+# ---------------------------------------------------------------------------
+# The llama block: pinned to what the harness read before blocks were
+# modules (weights bitwise, counts exactly; the reference's float32 logits
+# to within what another build of XLA:CPU may round differently)
+# ---------------------------------------------------------------------------
+
+PINNED_WEIGHTS = {
+    "tied, q/k/v biases":
+        "19ef7fa6cedac72188c4d610eaa9e46bdff0ef93181e23956d4be8071ab2bb5a",
+    "untied, no biases":
+        "159d8017ca9c64e2aa2ddf9227187c99a27fa77cb44dc874549a47a483a368c3",
+}
+PINNED_SPANS = [(161, 0, 40), (256, 100, 300), (42, 5, 1211), (8, 0, 1)]
+PINNED_WORK = {   # window_work at keep 4 over PINNED_SPANS, weight_bytes
+    "qwen2_0_5b": ({"model_flops": 1671450955776, "attn_flops": 75819319296,
+                    "kv_bytes": 1679907840}, 987922432),
+    "yi_6b": ({"model_flops": 19186955321344, "attn_flops": 462136803328,
+               "kv_bytes": 8959508480}, 11597250560),
+}
+PINNED_LOGITS = {  # sum, three logits, greedy rows 16..39 of a 40-token run
+    "reference": (-0.0896139144897461,
+                  [-0.1287555992603302, -0.17070049047470093,
+                   -0.30286532640457153],
+                  [127, 62, 223, 62, 127, 62, 175, 235, 164, 65, 235, 158,
+                   235, 51, 75, 175, 82, 219, 155, 27, 238, 225, 150, 225]),
+    "control": (0.71136474609375,
+                [-0.14898681640625, -0.10674667358398438,
+                 -0.35135650634765625],
+                [127, 62, 223, 62, 127, 62, 175, 235, 164, 66, 235, 158,
+                 106, 51, 75, 175, 82, 219, 155, 133, 164, 225, 150, 225]),
+}
+
+
+def _tree_hash(params) -> str:
+    import hashlib
+
+    import jax
+
+    h = hashlib.sha256()
+    leaves = jax.tree_util.tree_flatten_with_path(params)[0]
+    for path, leaf in sorted(leaves, key=lambda kv: jax.tree_util.keystr(kv[0])):
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(np.asarray(leaf).view(np.uint16).tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("form", sorted(PINNED_WEIGHTS))
+def test_llama_block_weights_are_pinned(form):
+    d = load_test_cell("tiny").dims
+    if form.startswith("untied"):
+        d = dict(d, tied=False, qkv_bias=False)
+    params = weights.make_params(LLAMA.layout(d), 2 ** 40 + 3)
+    assert _tree_hash(params) == PINNED_WEIGHTS[form]
+
+
+@pytest.mark.parametrize("name", sorted(PINNED_WORK))
+def test_llama_block_work_is_pinned(name):
+    d = config_dims(name)
+    work, wbytes = PINNED_WORK[name]
+    assert flops.window_work(LLAMA, d, 4, PINNED_SPANS) == work
+    assert flops.weight_bytes(LLAMA, d) == wbytes
+
+
+@pytest.mark.parametrize("precision", sorted(PINNED_LOGITS))
+def test_llama_block_reference_is_pinned(precision):
+    import jax.numpy as jnp
+
+    from chipbench import reference
+
+    d = load_test_cell("tiny").dims
+    params = weights.make_params(LLAMA.layout(d), 2 ** 40 + 3)
+    toks = np.zeros(reference.pad_len(40), np.int32)
+    toks[:40] = np.random.default_rng(5).integers(0, 256, 40)
+    dkey = tuple(sorted(d.items()))
+    h = LLAMA.final_hidden(params, jnp.asarray(toks), 17, d=dkey, keep=4,
+                           precision=precision)[16:40]
+    lg = np.asarray(LLAMA.logits_rows(params, h, d=dkey, precision=precision))
+    total, some, greedy = PINNED_LOGITS[precision]
+    assert lg.argmax(-1).tolist() == greedy
+    np.testing.assert_allclose(lg[[0, 5, 23], [3, 100, 255]], some,
+                               rtol=1e-5, atol=1e-6)
+    assert float(lg.sum()) == pytest.approx(total, abs=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# Loading cells: plain errors
+# ---------------------------------------------------------------------------
+
+def _write_cell(tmp_path, *, block="llama", serve=None, chips=1, drop=()):
+    config = spec.load_json(TESTDATA / "tiny_config.json")
+    if block is None:
+        config.pop("block")
+    else:
+        config["block"] = block
+    work = spec.load_json(TESTDATA / "tiny.json")
+    work["chips"] = chips
+    work["serve"].update(serve or {})
+    for key in drop:
+        del work["serve"][key]
+    (tmp_path / "tiny_config.json").write_text(json.dumps(config))
+    (tmp_path / "cell.json").write_text(json.dumps(work))
+
+
+@pytest.mark.parametrize("case,kw,says", [
+    ("unknown block", {"block": "no_such_block"}, "unknown block 'no_such_block'"),
+    ("no block", {"block": None}, "names no block"),
+    ("path for a block", {"block": "../run"}, "is not a name"),
+    ("four chips without a mesh", {"chips": 4}, "gives serve.mesh"),
+    ("mesh of two chips", {"chips": 4, "serve": {"mesh": "2x1"}},
+     "gives serve.mesh"),
+    ("one chip with a mesh", {"serve": {"mesh": "2x2"}}, "has no serve.mesh"),
+    ("compressed without a keep", {"drop": ("keep",)}, "gives serve.keep"),
+])
+def test_malformed_cell_is_a_plain_error(tmp_path, case, kw, says):
+    _write_cell(tmp_path, **kw)
+    with pytest.raises(ValueError, match=re.escape(says)):
+        spec.load_cell("cell", tmp_path, tmp_path)
+
+
+def test_block_without_its_functions_is_a_plain_error(tmp_path):
+    (tmp_path / "half.py").write_text("def model_dims(config):\n    return {}\n")
+    with pytest.raises(ValueError, match="does not define"):
+        spec.load_block("half", tmp_path)
 
 
 # ---------------------------------------------------------------------------
@@ -211,12 +363,13 @@ def test_every_cell_names_its_files():
         assert cell.workload["config"] == w["config"] in names
         assert cell.workload["chips"] == w["chips"]
         assert cell.workload["why"] == w["why"]
-        sv, pages = cell.serve, cell.serve["pool_pages"]
+        sv = cell.serve
         # no request's page horizon can exceed the pool, and every prompt
         # fits the prefill ladder: no operation fails on this traffic
         t = cell.traffic
-        horizon = (t["prompt"]["max"] + t["output"]["max"] - 1) // 8
-        assert horizon <= pages
+        if cell.keep is not None:
+            horizon = (t["prompt"]["max"] + t["output"]["max"] - 1) // 8
+            assert horizon <= sv["pool_pages"]
         assert t["prompt"]["max"] <= max(sv["prefill_buckets"]) <= sv["max_seq"]
         assert t["prompt"]["max"] + t["output"]["max"] <= sv["max_seq"]
     for m in bench["end_to_end"] + bench["per_layer"]:
@@ -228,7 +381,7 @@ def test_config_files_match_the_program_registry(name):
     sys.path.insert(0, str(ROOT / "src"))
     from repro.configs.base import get_config
 
-    d = spec.model_dims(spec.load_json(spec.CONFIG_DIR / f"{name}.json"))
+    d = config_dims(name)
     c = get_config(name)
     assert (c.n_layers, c.d_model, c.n_heads, c.n_kv_heads,
             c.resolved_head_dim, c.d_ff, c.vocab_size, c.qkv_bias,
@@ -237,21 +390,25 @@ def test_config_files_match_the_program_registry(name):
         d["head_dim"], d["d_ff"], d["vocab"], d["qkv_bias"], d["tied"])
 
 
-def test_weights_have_the_program_layout():
+def _layouts_agree(name):
     sys.path.insert(0, str(ROOT / "src"))
     import jax
 
-    import run
-    from repro.models import api as model_api
-
-    cell = spec.load_cell("tiny", TESTDATA, TESTDATA)
-    dims = spec.model_dims(cell.config)
-    cfg = run.arch_config(cell)
-    ours = jax.eval_shape(lambda: weights._make(weights.base_key(2 ** 40 + 3), dims))
+    cell = load_test_cell(name)
+    leaves = cell.block.layout(cell.dims)
+    ours = jax.eval_shape(lambda: weights._make(weights.base_key(2 ** 40 + 3), leaves))
     theirs = jax.eval_shape(
-        lambda: model_api.build(cell.config["arch"], cfg).init(jax.random.PRNGKey(0)))
+        lambda: cell.block.program(cell.config).init(jax.random.PRNGKey(0)))
     shape = lambda t: jax.tree.map(lambda a: (a.shape, str(a.dtype)), t)
     assert shape(ours) == shape(theirs)
+
+
+def test_weights_have_the_program_layout():
+    _layouts_agree("tiny")
+
+
+def test_block_of_testdata_has_the_program_layout():
+    _layouts_agree("tiny_ln_relu2")
 
 
 def test_reference_codec_matches_the_program_codec():
@@ -290,18 +447,24 @@ def test_run_refuses_without_a_chip():
     assert "no tpu" in p.stderr
 
 
-def _tiny_run(fault=None, control=False, seed=2 ** 31 + 77):
+def load_test_cell(name: str):
+    """A cell of `testdata/`; `tiny_ln_relu2`'s block is `testdata/`'s too."""
+    blocks = TESTDATA / "blocks" if name == "tiny_ln_relu2" else spec.BLOCK_DIR
+    return spec.load_cell(name, TESTDATA, TESTDATA, blocks)
+
+
+def _tiny_run(fault=None, control=False, seed=2 ** 31 + 77, name="tiny"):
     sys.path.insert(0, str(ROOT / "src"))
     import run
 
-    cell = spec.load_cell("tiny", TESTDATA, TESTDATA)
-    return run.run_cell(cell, seed, 1.0, False, end_to_end=E2E, per_layer=[],
+    return run.run_cell(load_test_cell(name), seed, 1.0, False,
+                        end_to_end=E2E, per_layer=[],
                         t_start=time.perf_counter(), platform="cpu",
                         fault=fault, control=control, log=lambda m: None)
 
 
-def test_sound_run_is_correct_and_control_is_not():
-    out, read = _tiny_run()
+def _sound_and_control(name):
+    out, read = _tiny_run(name=name)
     assert out["correct"], out["checks"]
     assert out["checks"]["logit_gap"]["value"] == read["logit_gap"]
     assert set(out["metrics"]) == {m["name"] for m in E2E}
@@ -309,12 +472,69 @@ def test_sound_run_is_correct_and_control_is_not():
     json.dumps(out)
     # the control in the program's place: the harness's own comparison
     # must come out as not correct
-    out, read = _tiny_run(control=True)
+    out, read = _tiny_run(control=True, name=name)
     limit = out["checks"]["logit_gap"]["limit"]
     assert not out["correct"], (out["checks"], read)
     assert out["checks"]["logit_gap"]["value"] == read["control_logit_gap"]
     assert read["logit_gap"] <= limit < read["control_logit_gap"]
     assert out["failed"] >= 1
+
+
+def test_sound_run_is_correct_and_control_is_not():
+    _sound_and_control("tiny")
+
+
+def test_rawkv_run_is_correct_and_control_is_not():
+    assert load_test_cell("tiny_rawkv").keep is None
+    _sound_and_control("tiny_rawkv")
+
+
+def test_block_of_testdata_alone_runs_and_its_control_does_not():
+    """A configuration that is new files only: block, configuration and
+    workload all under `testdata/`, none of them known to the harness."""
+    cell = load_test_cell("tiny_ln_relu2")
+    assert Path(cell.block.__file__).parent == TESTDATA / "blocks"
+    assert cell.dims["norm_eps"] == cell.config["config"]["layer_norm_eps"]
+    _sound_and_control("tiny_ln_relu2")
+
+
+def test_a_block_imported_by_another_is_one_module():
+    """The package's blocks are its modules: a block outside the package
+    that imports one shares it (and its jit caches) with the harness."""
+    from chipbench.blocks import llama
+
+    assert LLAMA is llama
+    assert load_test_cell("tiny_ln_relu2").block.attention is llama.attention
+
+
+MESH_RUN = """
+import json, sys, time
+sys.path[:0] = [{bench!r}, {src!r}]
+import jax
+import run
+from chipbench import spec
+cell = spec.load_cell("tiny_mesh", spec.BENCH_DIR / "testdata",
+                      spec.BENCH_DIR / "testdata")
+out, read = run.run_cell(cell, {seed}, 1.0, False, end_to_end=[],
+                         per_layer=[], t_start=time.perf_counter(),
+                         platform="cpu", log=lambda m: None)
+print(json.dumps(out))
+"""
+
+
+def test_mesh_run_is_correct_on_four_devices():
+    import os
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    code = MESH_RUN.format(bench=str(BENCH), src=str(ROOT / "src"),
+                           seed=2 ** 33 + 17)
+    p = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-4000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["device"]["count"] == 4
+    assert out["correct"], out["checks"]
 
 
 class _Req:
@@ -344,5 +564,12 @@ def test_sample_takes_the_longest_and_both_halves():
 @pytest.mark.parametrize("fault", ["token", "state", "half_batch"])  # run.FAULTS
 def test_planted_fault_is_not_correct(fault):
     out, read = _tiny_run(fault=fault)
+    assert not out["correct"]
+    assert read["logit_gap"] > out["checks"]["logit_gap"]["limit"]
+
+
+@pytest.mark.parametrize("fault", ["token", "state", "half_batch"])  # run.FAULTS
+def test_planted_fault_is_not_correct_with_raw_kv(fault):
+    out, read = _tiny_run(fault=fault, name="tiny_rawkv")
     assert not out["correct"]
     assert read["logit_gap"] > out["checks"]["logit_gap"]["limit"]

@@ -238,7 +238,8 @@ def test_host_plane_changes_no_existing_reading(profile, tmp_path):
     peaks = {"bf16_flops": 1e12, "hbm_bytes_s": 1e11}
 
     def read_all(tr):
-        ctx = {"trace": tr, "work": work, "peaks": peaks}
+        ctx = {"trace": tr, "work": work, "peaks": peaks, "keep": 4,
+               "chips": 1}
         return {m: metrics.load_reader(m)(ctx) for m in (
             "idle_share", "compress_share", "attend_paged_roofline",
             "prefill_share")}, run._breakdown(tr)
